@@ -121,6 +121,22 @@ def _gc_proc(root: str, stop_path: str, out_q) -> None:
     out_q.put(agg)
 
 
+def _start_on_cpu(procs) -> None:
+    """Start spawned children with JAX pinned to the CPU: their states
+    are numpy (``use_kernel=False``), and an accelerator belongs to one
+    process, so none of them may reach for it."""
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        for p in procs:
+            p.start()
+    finally:
+        if prev is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
+
+
 def _pct(xs: List[float], q: float) -> float:
     return float(np.percentile(np.asarray(xs), q)) if xs else 0.0
 
@@ -138,13 +154,12 @@ def run(cfg, verbose: bool = True) -> Dict[str, Any]:
         q = ctx.Queue()
         gq = ctx.Queue()
         gc = ctx.Process(target=_gc_proc, args=(root, stop_path, gq))
-        gc.start()
+        _start_on_cpu([gc])
         t_wall = time.perf_counter()
         procs = [ctx.Process(target=_writer_proc,
                              args=(root, i, n_saves, rows, tmp_saves, q))
                  for i in range(n_writers)]
-        for p in procs:
-            p.start()
+        _start_on_cpu(procs)
         writers = [q.get(timeout=600) for _ in procs]
         for p in procs:
             p.join()

@@ -15,10 +15,6 @@ leaves digest on the host and would hide the sync count under test.  Two
   * speculation hit rate (`n_spec_hits / (hits + misses)`) and
     corrective-sync count,
   * median warm save latency for both paths,
-  * a roofline-modeled transfer floor: bytes that must cross HBM for
-    digesting + the dirty payload over `roofline.HBM_BW` — the fused
-    path's win is *latency* (one round-trip), not bytes, so the floor
-    is identical for both and anchors the latency numbers,
   * bit-identity of manifests/pods between the two paths.
 
 The trajectory dumps to ``experiments/bench/BENCH_singlesync.json`` so
@@ -32,8 +28,6 @@ import time
 from typing import Any, Dict, List, Tuple
 
 import numpy as np
-
-from .roofline import HBM_BW
 
 OUT_JSON = os.path.join(os.path.dirname(__file__), "..", "experiments",
                         "bench", "BENCH_singlesync.json")
@@ -140,13 +134,6 @@ def bench_singlesync(quick: bool = False) -> List[Dict]:
     hit_rate = hits / max(hits + misses, 1)
     corrective = [s["n_corrective_syncs"] for s in fus.save_stats[warm]]
 
-    # roofline transfer floor: every active byte is read once to digest
-    # (HBM-rate on device), and dirty-pod payload bytes cross once more.
-    state_bytes = 2 * rows * d * 4        # emb + mu, float32
-    dirty_bytes = sum(s["n_dirty_chunks"] for s in fus.save_stats[warm]) \
-        / max(len(fus.save_stats[warm]), 1) * chunk
-    floor_ms = (state_bytes + dirty_bytes) / HBM_BW * 1e3
-
     row = {
         "bench": "singlesync", "workload": "sparse_update_device",
         "syncs_per_warm_save_fused": float(np.median(fus_syncs[warm])),
@@ -160,7 +147,6 @@ def bench_singlesync(quick: bool = False) -> List[Dict]:
                                      3),
         "t_save_ms_twosync_p50": round(1e3 * float(np.median(ref_total[warm])),
                                        3),
-        "hbm_floor_ms": round(floor_ms, 4),
         "artifacts_identical": bool(identical),
     }
 

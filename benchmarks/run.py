@@ -4,6 +4,8 @@
 
 Prints the contract CSV ``name,us_per_call,derived`` (one line per
 benchmark row) and writes full row dumps to experiments/bench/*.csv.
+A bench that raises prints an ``ERROR`` row, the rest still run, and
+the harness then exits non-zero.
 The ``incremental`` bench additionally dumps its per-save trajectory
 (t_graph, t_podding, t_total, reuse counters, for both the incremental
 and the from-scratch pipeline) to
@@ -20,6 +22,8 @@ import time
 from typing import Callable, Dict, List
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 from . import (bench_core, bench_fingerprint, bench_incremental,  # noqa: E402
                bench_serve_fleet)
@@ -54,9 +58,11 @@ def _derived_of(row: Dict) -> str:
 
 
 def main() -> None:
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--only", default=None)
     args = p.parse_args()
+    failed: List[str] = []
 
     os.makedirs(OUT_DIR, exist_ok=True)
     print("name,us_per_call,derived")
@@ -68,6 +74,7 @@ def main() -> None:
             rows = fn()
         except Exception as e:  # noqa: BLE001
             print(f"{name},0,ERROR:{type(e).__name__}:{e}")
+            failed.append(name)
             continue
         us = (time.perf_counter() - t0) * 1e6 / max(len(rows), 1)
         with open(os.path.join(OUT_DIR, f"{name}.csv"), "w", newline="") as f:
@@ -77,6 +84,8 @@ def main() -> None:
             w.writerows(rows)
         for row in rows:
             print(f"{name},{us:.1f},{_derived_of(row)}")
+    if failed:
+        sys.exit(f"benches failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

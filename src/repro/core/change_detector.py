@@ -84,12 +84,11 @@ class ChangeReport:
 
 class ChangeDetector:
     def __init__(self, *, chunk_bytes: int = 1 << 22, seed: int = 0,
-                 use_kernel: bool = True, interpret: bool = True,
-                 batched: bool = True, fused: bool = True):
+                 use_kernel: bool = True, batched: bool = True,
+                 fused: bool = True):
         self.chunk_bytes = chunk_bytes
         self.seed = seed
         self.use_kernel = use_kernel
-        self.interpret = interpret
         self.batched = batched
         self.fused = fused and batched
         # persistent key-indexed digest table: uint32 (N, 4) + key -> row
@@ -163,14 +162,14 @@ class ChangeDetector:
         if self.fused:
             res, self._dev_table = kbatch.digest_leaves_fused(
                 items, chunk_bytes=self.chunk_bytes, seed=self.seed,
-                use_kernel=self.use_kernel, interpret=self.interpret,
+                use_kernel=self.use_kernel,
                 table=self._dev_table, lookup=self._lookup_digest,
                 spec_keys=speculate)
             return res
         if self.batched:
             return kbatch.digest_leaves(
                 items, chunk_bytes=self.chunk_bytes, seed=self.seed,
-                use_kernel=self.use_kernel, interpret=self.interpret)
+                use_kernel=self.use_kernel)
         keys: List[str] = []
         mats: List[np.ndarray] = []
         leaf_rows: Dict[str, int] = {}
@@ -183,7 +182,7 @@ class ChangeDetector:
             else:
                 dig = kops.leaf_fingerprint(
                     arr, chunk_bytes=self.chunk_bytes, seed=self.seed,
-                    use_kernel=self.use_kernel, interpret=self.interpret)
+                    use_kernel=self.use_kernel)
                 n_syncs += 1
             leaf_rows[lkey] = row
             keys.extend(f"{lkey}#[{ci}]" for ci in range(dig.shape[0]))

@@ -228,6 +228,7 @@ copy-off opt-out) disables the guard with the copies.
 """
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import time as _time
 import warnings
@@ -622,6 +623,13 @@ class Chipmink:
                          t_graph, n_leaf_copies=0, branch=None) -> None:
         stats: Dict[str, Any] = {"time_id": time_id, "t_graph": t_graph,
                                  "n_leaf_copies": n_leaf_copies}
+        # Only the newest snapshot keeps its arrays: this body reads the
+        # previous graph by structure alone (AVF), and checkout, which
+        # drains saves first, reads the graph this body leaves behind.
+        # Dropping the older arrays frees that device state now.
+        if self._prev_graph is not None and self._prev_graph is not graph:
+            self._prev_graph = dataclasses.replace(self._prev_graph,
+                                                   arrays={})
         if ginfo is not None:
             stats["t_graph_inc"] = t_graph
             stats["n_nodes_reused"] = ginfo.n_nodes_reused

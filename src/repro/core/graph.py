@@ -318,6 +318,15 @@ def chunk_slice(arr: Any, node: Node) -> Any:
     return arr.reshape(-1)[lo:hi]
 
 
+def fetch_whole_leaf(n_wanted: int, n_chunks: int) -> bool:
+    """Fetch policy for one device leaf of which `n_wanted` of its
+    `n_chunks` chunks are wanted on the host.  With at least half wanted
+    the leaf crosses whole and is sliced on the host: no device copy, and
+    at most twice the wanted bytes cross the link.  Otherwise only the
+    wanted chunks cross, cut out on the device."""
+    return 2 * n_wanted >= n_chunks
+
+
 def rebuild_tree(flat: Dict[str, Any]) -> Any:
     """Rebuild a nested dict tree from path-keyed leaves (inverse of flatten).
 

@@ -25,7 +25,7 @@ import msgpack
 import numpy as np
 
 from .graph import (ALIAS, CHUNK, CONTAINER, LEAF, SCALAR, Node, ObjectGraph,
-                    chunk_slice, path_str)
+                    chunk_slice, fetch_whole_leaf, path_str)
 from .lga import BUNDLE, SPLIT_CONTINUE, SPLIT_FINAL, PodState, PoddingPolicy
 from .memo import CROSS_POD_OFFSET, GlobalMemoSpace
 
@@ -205,36 +205,50 @@ def batched_chunk_fetch(graph: ObjectGraph, nodes: Sequence[Node]
                         ) -> Tuple[Callable[[Node], bytes], int]:
     """Gather payload bytes of every CHUNK node in `nodes` at once.
 
-    Host (numpy) chunks are sliced directly; all device (jax) chunk
-    slices are fetched with a **single** `jax.device_get` over the full
+    Host (numpy) chunks are sliced directly; all device (jax) payloads
+    are fetched with a **single** `jax.device_get` over the full
     dirty-chunk set — replacing the per-chunk blocking `np.asarray` the
-    serializer used to pay.  Returns (lookup fn for serialize_pod,
-    number of device syncs issued: 0 or 1).
+    serializer used to pay.  Each device leaf crosses whole or as its
+    requested chunks, as `fetch_whole_leaf` decides, so a dense save
+    holds no second copy of the state on the device.  Returns (lookup fn
+    for serialize_pod, number of device syncs issued: 0 or 1).
     """
     import jax
 
     host_bytes: Dict[str, bytes] = {}
-    dev_keys: List[str] = []
-    dev_parts: List[Any] = []
+    dev_chunks: Dict[str, List[Node]] = {}
     for node in nodes:
         if node.kind != CHUNK:
             continue
-        arr = graph.arrays[path_str(node.path)]
-        part = chunk_slice(arr, node)
+        lkey = path_str(node.path)
+        arr = graph.arrays[lkey]
         if isinstance(arr, np.ndarray):
-            host_bytes[node.key] = np.ascontiguousarray(part).tobytes()
+            host_bytes[node.key] = np.ascontiguousarray(
+                chunk_slice(arr, node)).tobytes()
         else:
-            dev_keys.append(node.key)
-            dev_parts.append(part)
+            dev_chunks.setdefault(lkey, []).append(node)
+    # (device array, chunks it serves, whether it is the whole leaf)
+    parts: List[Tuple[Any, List[Node], bool]] = []
+    for lkey, chunks in dev_chunks.items():
+        arr = graph.arrays[lkey]
+        n_chunks = len(graph.node(graph.by_key[lkey]).children)
+        if fetch_whole_leaf(len(chunks), n_chunks):
+            parts.append((arr, chunks, True))
+        else:
+            parts.extend((chunk_slice(arr, node), [node], False)
+                         for node in chunks)
     n_syncs = 0
-    if dev_parts:
-        fetched = jax.device_get(dev_parts)
+    if parts:
+        fetched = jax.device_get([p for p, _, _ in parts])
         n_syncs = 1
         # release each host array as it is converted so peak memory stays
-        # ~1x the dirty payload, not 2x
-        for i, key in enumerate(dev_keys):
-            host_bytes[key] = np.asarray(fetched[i]).tobytes()
+        # ~1x the fetched payload, not 2x
+        for i, (_, chunks, whole) in enumerate(parts):
+            host = np.asarray(fetched[i])
             fetched[i] = None
+            for node in chunks:
+                part = chunk_slice(host, node) if whole else host
+                host_bytes[node.key] = np.ascontiguousarray(part).tobytes()
 
     def get(node: Node) -> bytes:
         return host_bytes[node.key]

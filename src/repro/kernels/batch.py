@@ -16,13 +16,15 @@ object graph:
     so bucket padding is digest-neutral.
   * **Packer** — a jit'd function (cached on the plan) bitcasts every
     leaf to its uint32 word stream, reshapes it onto the chunk grid, and
-    concatenates all rows of a bucket into one (C_bucket, W_bucket)
-    matrix, padded up to a power-of-two row count so bucket shapes repeat
-    across saves and the kernel's jit cache stops recompiling.
+    writes it in place into its rows of one (C_bucket, W_bucket) matrix
+    per bucket.  Row counts are padded up to a multiple of the kernel's
+    row block; the plan depends only on the leaf specs, so bucket shapes
+    repeat across saves of one pytree and the kernel's jit cache stops
+    recompiling.
   * **Dispatch** — one `pallas_call` per bucket, row-blocked
     (`fingerprint.fingerprint_words(rows=...)`): a grid cell digests up
-    to ``MAX_BLOCK_ROWS`` chunks at once, so small chunks cost a fraction
-    of a dispatch instead of one each.
+    to ``fingerprint.MAX_BLOCK_ROWS`` chunks at once, so small chunks
+    cost a fraction of a dispatch instead of one each.
   * **Fetch** — all (C, 4) digest rows of all buckets leave the device in
     a **single** `jax.device_get` at the end of the save (the
     single-sync contract; `DigestResult.n_syncs` reports it).
@@ -61,19 +63,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.graph import ObjectGraph, chunk_grid
-from .fingerprint import TILE, fingerprint_words, fingerprint_words_cmp
+from ..core.graph import ObjectGraph, chunk_grid, fetch_whole_leaf
+from .fingerprint import (TILE, block_rows, fingerprint_words,
+                          fingerprint_words_cmp, pow2ceil)
 from .ref import (fingerprint_words_cmp_ref, fingerprint_words_np,
                   fingerprint_words_ref)
 
 #: smallest bucket word width (512 B) — tiny leaves share one bucket
 MIN_BUCKET_WORDS = 128
-#: chunk rows digested per grid cell (block row count, power of two)
-MAX_BLOCK_ROWS = 64
-
-
-def pow2ceil(n: int) -> int:
-    return 1 << max(0, int(n - 1).bit_length())
+#: chunks per piece when the packer cuts a large leaf along its rows
+PACK_PIECE_CHUNKS = 32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,7 +93,7 @@ class LeafSlot:
 class BucketSpec:
     width: int               # words per row (power of two)
     n_rows: int              # real chunk rows
-    padded_rows: int         # pow2ceil(n_rows) — shape-stable across saves
+    padded_rows: int         # n_rows up to a multiple of block_rows
     block_rows: int          # rows per kernel grid cell
     tile: int                # inner tile width for the kernel
 
@@ -135,8 +134,8 @@ def plan_leaves(specs: Tuple[Tuple[str, Tuple[int, ...], str], ...],
     buckets = []
     for width in sorted(rows_in_bucket):
         n_rows = rows_in_bucket[width]
-        padded = pow2ceil(n_rows)
-        block = min(MAX_BLOCK_ROWS, padded)
+        block = block_rows(n_rows)
+        padded = -(-n_rows // block) * block
         buckets.append(BucketSpec(width=width, n_rows=n_rows,
                                   padded_rows=padded, block_rows=block,
                                   tile=min(TILE, width)))
@@ -181,38 +180,56 @@ def _plan_lengths(plan: BatchPlan) -> Tuple[np.ndarray, ...]:
     return tuple(out[b.width] for b in plan.buckets)
 
 
-def _pack_leaf_words_jnp(slot: LeafSlot, arr: Any) -> jnp.ndarray:
+def _words_matrix(slot: LeafSlot, arr: Any, n_chunks: int) -> jnp.ndarray:
+    """uint32 (n_chunks, bucket) word rows of `arr`, the leaf's chunks
+    from a chunk boundary on (zero words pad the last chunk and each
+    row's width)."""
     from .ops import to_words
     w = to_words(arr)
-    need = slot.n_chunks * slot.words_per_chunk
-    have = int(w.shape[0])
-    if have != need:
-        w = jnp.pad(w, (0, need - have))
-    mat = w.reshape(slot.n_chunks, slot.words_per_chunk)
+    need = n_chunks * slot.words_per_chunk
+    if int(w.shape[0]) != need:
+        w = jnp.pad(w, (0, need - int(w.shape[0])))
+    mat = w.reshape(n_chunks, slot.words_per_chunk)
     if slot.words_per_chunk != slot.bucket:
         mat = jnp.pad(mat, ((0, 0), (0, slot.bucket - slot.words_per_chunk)))
     return mat
 
 
+def _leaf_pieces(slot: LeafSlot, arr: Any):
+    """(first chunk, word rows) pieces of one leaf.
+
+    A leaf whose chunks are whole rows of its leading axis is cut along
+    that axis, `PACK_PIECE_CHUNKS` chunks at a time: laying a piece out
+    as word rows is a temporary of that piece only, where the whole leaf
+    at once costs a temporary of the leaf (on a TPU, a reshape that
+    changes the minor dimension moves the data)."""
+    row = int(np.prod(slot.shape[1:], dtype=np.int64))
+    if (len(slot.shape) < 2 or slot.elems % row
+            or slot.n_chunks <= PACK_PIECE_CHUNKS):
+        yield 0, _words_matrix(slot, arr, slot.n_chunks)
+        return
+    rows_per_chunk = slot.elems // row
+    for c0 in range(0, slot.n_chunks, PACK_PIECE_CHUNKS):
+        n = min(PACK_PIECE_CHUNKS, slot.n_chunks - c0)
+        part = arr[c0 * rows_per_chunk:(c0 + n) * rows_per_chunk]
+        yield c0, _words_matrix(slot, part, n)
+
+
 @functools.lru_cache(maxsize=512)
 def _packer_for(plan: BatchPlan):
     """jit'd: leaf arrays (plan order) -> per-bucket (padded_rows, width)
-    uint32 word matrices.  One dispatch packs the whole pytree."""
+    uint32 word matrices.  One dispatch packs the whole pytree.  Each
+    leaf's rows are written in place into a zeroed bucket matrix, piece
+    by piece, so the program holds no concatenated temporary the size of
+    the state beside its output."""
     def pack(*arrays):
-        rows: Dict[int, List[jnp.ndarray]] = {b.width: [] for b in plan.buckets}
+        out = {b.width: jnp.zeros((b.padded_rows, b.width), jnp.uint32)
+               for b in plan.buckets}
         for slot, arr in zip(plan.leaves, arrays):
-            rows[slot.bucket].append(_pack_leaf_words_jnp(slot, arr))
-        out = []
-        for b in plan.buckets:
-            # leaves were appended in plan order == row0 order
-            mats = sorted(zip((s.row0 for s in plan.leaves
-                               if s.bucket == b.width), rows[b.width]))
-            m = (jnp.concatenate([x for _, x in mats], axis=0)
-                 if len(mats) > 1 else mats[0][1])
-            if b.padded_rows != b.n_rows:
-                m = jnp.pad(m, ((0, b.padded_rows - b.n_rows), (0, 0)))
-            out.append(m)
-        return tuple(out)
+            for c0, mat in _leaf_pieces(slot, arr):
+                out[slot.bucket] = jax.lax.dynamic_update_slice(
+                    out[slot.bucket], mat, (slot.row0 + c0, 0))
+        return tuple(out[b.width] for b in plan.buckets)
 
     return jax.jit(pack)
 
@@ -293,15 +310,14 @@ def seed_device_table(plan: BatchPlan,
 
 
 def _digest_device(plan: BatchPlan, arrays: Sequence[Any], *, seed: int,
-                   use_kernel: bool, interpret: bool) -> List[np.ndarray]:
+                   use_kernel: bool) -> List[np.ndarray]:
     packed = _packer_for(plan)(*arrays)
     lengths = _plan_lengths(plan)
     digs = []
     for b, words, lens in zip(plan.buckets, packed, lengths):
         if use_kernel:
             d = fingerprint_words(words, jnp.asarray(lens), seed=seed,
-                                  interpret=interpret, tile=b.tile,
-                                  rows=b.block_rows)
+                                  tile=b.tile, rows=b.block_rows)
         else:
             d = fingerprint_words_ref(words, jnp.asarray(lens), seed=seed)
         digs.append(d)
@@ -327,8 +343,7 @@ def _digest_host(plan: BatchPlan, arrays: Sequence[np.ndarray], *,
 
 
 def digest_leaves(items: Sequence[Tuple[str, Any]], *, chunk_bytes: int,
-                  seed: int = 0, use_kernel: bool = True,
-                  interpret: bool = True) -> DigestResult:
+                  seed: int = 0, use_kernel: bool = True) -> DigestResult:
     """Digest every chunk of the given (leaf key, array) pairs.
 
     Device (jax) leaves go through the bucketed Pallas path and cost one
@@ -356,8 +371,7 @@ def digest_leaves(items: Sequence[Tuple[str, Any]], *, chunk_bytes: int,
         arrays = [a for _, a in group]
         if is_dev:
             bucket_digs = _digest_device(plan, arrays, seed=seed,
-                                         use_kernel=use_kernel,
-                                         interpret=interpret)
+                                         use_kernel=use_kernel)
             n_syncs += 1
         else:
             bucket_digs = _digest_host(plan, arrays, seed=seed)
@@ -375,59 +389,96 @@ def digest_leaves(items: Sequence[Tuple[str, Any]], *, chunk_bytes: int,
 
 
 def _digest_device_fused(plan: BatchPlan, arrays: Sequence[Any], *,
-                         seed: int, use_kernel: bool, interpret: bool,
+                         seed: int, use_kernel: bool,
                          table: DeviceTable,
-                         spec_local: Dict[int, np.ndarray]):
-    """Fused per-bucket digest+compare plus speculative row compaction.
+                         spec_rows: Dict[int, np.ndarray]):
+    """Fused per-bucket digest+compare plus the speculated payload.
 
-    Returns (digest mats, dirty masks, {bucket idx: fetched spec rows},
-    new DeviceTable) after exactly ONE `jax.device_get` covering all
-    three result classes.
+    `spec_rows` maps a bucket index to the sorted bucket rows whose
+    payload to fetch.  Each leaf with speculated rows crosses whole or
+    sparse, as `fetch_whole_leaf` decides.  The rows of sparse leaves are
+    gathered from the packed bucket into one dense buffer — rows are the
+    chunks' little-endian word streams, so a row's first true-length
+    bytes ARE the payload — padded to a power-of-two row count
+    (repeating the first row) so the gather's jit cache stops recompiling
+    as the speculation set fluctuates.
+
+    Returns (digest mats, dirty masks, {chunk key: payload bytes}, new
+    DeviceTable) after exactly ONE `jax.device_get`.
     """
-    packed = _packer_for(plan)(*arrays)
+    keys, _ = _plan_slots(plan)
     lengths = _plan_lengths(plan)
+    base = np.cumsum([0] + [b.n_rows for b in plan.buckets])
+    packed = list(_packer_for(plan)(*arrays))
     digs_dev: List[Any] = []
     masks_dev: List[Any] = []
-    spec_dev: List[Tuple[int, Any]] = []
-    for bi, (b, words, lens) in enumerate(zip(plan.buckets, packed,
-                                              lengths)):
-        prev = table.digs[bi]
+    gathers: List[Tuple[int, np.ndarray, Any]] = []
+    # (bucket index, slot, device leaf, speculated chunk indices)
+    leaves: List[Tuple[int, LeafSlot, Any, np.ndarray]] = []
+    for bi, b in enumerate(plan.buckets):
+        # only the kernel and the gather below read the packed bucket:
+        # holding no reference past them frees it as soon as they finish,
+        # not after the fetch
+        words, packed[bi] = packed[bi], None
+        lens = jnp.asarray(lengths[bi])
         if use_kernel:
-            d, m = fingerprint_words_cmp(words, jnp.asarray(lens), prev,
-                                         seed=seed, interpret=interpret,
-                                         tile=b.tile, rows=b.block_rows)
+            d, m = fingerprint_words_cmp(words, lens, table.digs[bi],
+                                         seed=seed, tile=b.tile,
+                                         rows=b.block_rows)
         else:
-            d, m = fingerprint_words_cmp_ref(words, jnp.asarray(lens),
-                                             prev[:b.padded_rows],
+            d, m = fingerprint_words_cmp_ref(words, lens, table.digs[bi],
                                              seed=seed)
         digs_dev.append(d)
         masks_dev.append(m)
-        rows = spec_local.get(bi)
-        if rows is not None and len(rows):
-            # compaction: gather the packed word rows of the speculated
-            # chunks into one dense (n_spec, width) buffer.  Rows are
-            # already the chunk's uint32 word stream, so the buffer IS
-            # the payload (true byte lengths slice off padding on host).
-            spec_dev.append((bi, words[jnp.asarray(rows, jnp.int32)]))
-    host = jax.device_get([digs_dev, masks_dev,
-                           [m for _, m in spec_dev]])  # the ONE sync
+        rows = spec_rows.get(bi)
+        if rows is None:
+            continue
+        sparse: List[np.ndarray] = []
+        for slot, arr in zip(plan.leaves, arrays):
+            if slot.bucket != b.width:
+                continue
+            lo, hi = np.searchsorted(rows, [slot.row0,
+                                            slot.row0 + slot.n_chunks])
+            if hi == lo:
+                continue
+            if fetch_whole_leaf(int(hi - lo), slot.n_chunks):
+                leaves.append((bi, slot, arr, rows[lo:hi] - slot.row0))
+            else:
+                sparse.append(rows[lo:hi])
+        if sparse:
+            rows = np.concatenate(sparse)
+            pad = np.full(pow2ceil(len(rows)) - len(rows), rows[0])
+            idx = jnp.asarray(np.concatenate([rows, pad]), jnp.int32)
+            gathers.append((bi, rows, words[idx]))
+    del words
+    host = jax.device_get([digs_dev, masks_dev, [g for *_, g in gathers],
+                           [a for _, _, a, _ in leaves]])  # the ONE sync
+    payload: Dict[str, bytes] = {}
+    for (bi, rows, _), fetched in zip(gathers, host[2]):
+        for i, r in enumerate(rows):
+            payload[keys[base[bi] + r]] = \
+                fetched[i].tobytes()[:int(lengths[bi][r])]
+    for (bi, slot, _, chunk_ids), leaf in zip(leaves, host[3]):
+        flat = np.asarray(leaf).reshape(-1)
+        for ci in chunk_ids:
+            lo = ci * slot.elems
+            payload[keys[base[bi] + slot.row0 + ci]] = \
+                flat[lo:lo + slot.elems].tobytes()
     dig_mats = [np.asarray(h, np.uint32)[:b.n_rows]
                 for b, h in zip(plan.buckets, host[0])]
     masks = [np.asarray(h, np.uint8)[:b.n_rows]
              for b, h in zip(plan.buckets, host[1])]
-    spec_rows = {bi: np.asarray(h)
-                 for (bi, _), h in zip(spec_dev, host[2])}
     # padded digest rows stay on device as the next save's prev table:
     # every digested row is now trusted.
     new_table = DeviceTable(
         plan=plan, digs=digs_dev,
         valid=[np.ones((b.n_rows,), bool) for b in plan.buckets])
-    return dig_mats, masks, spec_rows, new_table
+    return dig_mats, masks, payload, new_table
 
 
 def digest_leaves_fused(items: Sequence[Tuple[str, Any]], *,
                         chunk_bytes: int, seed: int = 0,
-                        use_kernel: bool = True, interpret: bool = True,
+                        use_kernel: bool = True,
                         table: Optional[DeviceTable] = None,
                         lookup=None,
                         spec_keys: Optional[set] = None
@@ -474,45 +525,22 @@ def digest_leaves_fused(items: Sequence[Tuple[str, Any]], *,
                 # against zeros and are forced dirty below.
                 table = seed_device_table(
                     plan, lookup if lookup is not None else lambda k: None)
-            # speculated chunk keys -> (bucket, local row)
-            spec_local: Dict[int, List[int]] = {}
-            bucket_base: List[int] = []
-            off = 0
-            for b in plan.buckets:
-                bucket_base.append(off)
-                off += b.n_rows
+            # speculated chunk keys -> sorted local rows per bucket
+            spec_rows: Dict[int, List[int]] = {}
             if spec_keys:
                 row_of = {k: r for r, k in enumerate(plan_keys)}
+                base = np.cumsum([0] + [b.n_rows for b in plan.buckets])
                 for k in spec_keys:
                     r = row_of.get(k)
-                    if r is None:
-                        continue
-                    for bi in range(len(plan.buckets) - 1, -1, -1):
-                        if r >= bucket_base[bi]:
-                            spec_local.setdefault(bi, []).append(
-                                r - bucket_base[bi])
-                            break
-            spec_arr = {bi: np.asarray(sorted(rows), np.int64)
-                        for bi, rows in spec_local.items()}
-            # pad each gather to a power-of-two row count (repeating the
-            # first row) so the gather's jit cache stops recompiling when
-            # the speculation set fluctuates; extra rows are fetched and
-            # dropped (payload extraction walks only the real rows).
-            spec_padded = {
-                bi: np.concatenate(
-                    [r, np.full(pow2ceil(len(r)) - len(r), r[0], np.int64)])
-                for bi, r in spec_arr.items()}
-            dig_mats, masks, spec_fetched, new_table = _digest_device_fused(
-                plan, arrays, seed=seed, use_kernel=use_kernel,
-                interpret=interpret, table=table, spec_local=spec_padded)
+                    if r is not None:
+                        bi = int(np.searchsorted(base, r, side="right")) - 1
+                        spec_rows.setdefault(bi, []).append(r - int(base[bi]))
+            dig_mats, masks, spec_payload, new_table = _digest_device_fused(
+                plan, arrays, seed=seed, use_kernel=use_kernel, table=table,
+                spec_rows={bi: np.asarray(sorted(r), np.int64)
+                           for bi, r in spec_rows.items()})
+            payload.update(spec_payload)
             n_syncs += 1
-            lengths = _plan_lengths(plan)
-            for bi, rows in spec_arr.items():
-                fetched = spec_fetched[bi]
-                lens = lengths[bi]
-                for i, r in enumerate(rows):
-                    key = plan_keys[bucket_base[bi] + int(r)]
-                    payload[key] = fetched[i].tobytes()[:int(lens[r])]
             for bi, (b, m) in enumerate(zip(plan.buckets, masks)):
                 d = m.astype(np.int8)
                 d[~table.valid[bi]] = 1      # no trusted prev: dirty
@@ -538,7 +566,7 @@ def digest_leaves_fused(items: Sequence[Tuple[str, Any]], *,
 
 def tree_fingerprint_batched(graph: ObjectGraph, *, active_leaf_paths=None,
                              chunk_bytes: int = 1 << 22, seed: int = 0,
-                             use_kernel: bool = True, interpret: bool = True
+                             use_kernel: bool = True
                              ) -> Tuple[Dict[str, bytes], int]:
     """Batched drop-in for `ops.tree_fingerprint`: {chunk key: 16-byte
     digest} for every (active) leaf, plus the number of device syncs paid
@@ -549,7 +577,7 @@ def tree_fingerprint_batched(graph: ObjectGraph, *, active_leaf_paths=None,
             continue
         items.append((leaf.key, graph.arrays[leaf.key]))
     res = digest_leaves(items, chunk_bytes=chunk_bytes, seed=seed,
-                        use_kernel=use_kernel, interpret=interpret)
+                        use_kernel=use_kernel)
     buf = res.mat.tobytes()
     out = {k: buf[16 * i:16 * (i + 1)] for i, k in enumerate(res.keys)}
     return out, res.n_syncs

@@ -9,9 +9,8 @@ computes the 128-bit digest *on device*:
     blocks (TILE = 4096 words = 16 KiB; last-dim multiple of 128 lanes),
   * per block, four weighted sums are accumulated on the VPU (integer
     multiply-add only; no MXU) — arithmetic intensity ≈ 1 op/byte, so the
-    kernel is memory-bound by construction and runs at HBM rate
-    (~819 GB/s on v5e vs ~10-30 GB/s/core for host xxhash behind a
-    ~16 GB/s PCIe hop),
+    kernel is memory-bound by construction: its ceiling is the HBM peak
+    (published for v5e as 819 GB/s; not a measured rate),
   * only 16 bytes per chunk leave the device; clean chunks never move.
 
 The kernel is *row-blocked*: a grid cell digests `rows` chunks at once
@@ -38,6 +37,7 @@ decide dirtiness (the single-sync save contract in batch.py/ops.py).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +46,43 @@ from jax.experimental import pallas as pl
 from .ref import DIGEST_WORDS, LANE_PRIMES, PHI32, STREAM_SALT, mix32
 
 TILE = 4096  # uint32 words per VMEM block (16 KiB); multiple of 128 lanes
+#: chunk rows digested per grid cell (block row count, power of two)
+MAX_BLOCK_ROWS = 64
+
+
+def pow2ceil(n: int) -> int:
+    return 1 << max(0, int(n - 1).bit_length())
+
+
+def block_rows(n_rows: int) -> int:
+    """Rows per grid cell for a matrix of `n_rows` chunk rows.
+
+    Rows are padded up to a multiple of the block, so a block of fewer
+    than 8 rows always spans the whole row dimension — the only case in
+    which the TPU's (8, 128) tiling rule admits it."""
+    return min(MAX_BLOCK_ROWS, pow2ceil(n_rows))
+
+
+def pallas_interpret() -> bool:
+    """Pallas mode for the default backend: compiled on a TPU, the
+    interpreter on the CPU.  Any other backend has no kernel."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"the fingerprint kernel runs on 'tpu' (compiled) or 'cpu' "
+        f"(interpreted), not on {platform!r}")
+
+
+def _sum_u32(x):
+    """Wrap-around uint32 sum along axis 1.  The TPU compiler reduces no
+    unsigned integers, so the sum runs in int32: two's-complement
+    addition is the same mod 2^32."""
+    s = jnp.sum(jax.lax.bitcast_convert_type(x, jnp.int32), axis=1,
+                dtype=jnp.int32)
+    return jax.lax.bitcast_convert_type(s, jnp.uint32)
 
 
 def _fingerprint_kernel(words_ref, lengths_ref, out_ref, *, seed: int,
@@ -62,7 +99,7 @@ def _fingerprint_kernel(words_ref, lengths_ref, out_ref, *, seed: int,
     for d in range(DIGEST_WORDS):
         w = mix32(pos * jnp.uint32(LANE_PRIMES[d]) + jnp.uint32(seed)
                   + jnp.uint32((d * STREAM_SALT) & 0xFFFFFFFF))
-        partial.append(jnp.sum(x * w, axis=1, dtype=jnp.uint32))
+        partial.append(_sum_u32(x * w))
     part = jnp.stack(partial, axis=1)              # (rows, DIGEST_WORDS)
 
     @pl.when(j == 0)
@@ -89,8 +126,9 @@ def _fingerprint_cmp_kernel(words_ref, lengths_ref, prev_ref, out_ref,
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _compare():
-        diff = (out_ref[...] != prev_ref[...]).astype(jnp.uint32)
-        dirty_ref[...] = jnp.max(diff, axis=1, keepdims=True)
+        diff = (out_ref[...] != prev_ref[...]).astype(jnp.int32)
+        dirty_ref[...] = jnp.max(diff, axis=1,
+                                 keepdims=True).astype(jnp.uint32)
 
 
 def _pad_grid(words, lengths, tile, rows):
@@ -110,17 +148,22 @@ def _pad_grid(words, lengths, tile, rows):
 
 @functools.partial(jax.jit,
                    static_argnames=("seed", "interpret", "tile", "rows"))
-def fingerprint_words_cmp(words: jnp.ndarray, lengths: jnp.ndarray,
-                          prev: jnp.ndarray, *, seed: int = 0,
-                          interpret: bool = True, tile: int = TILE,
-                          rows: int = 1):
+def fingerprint_words_cmp(words, lengths, prev, *, seed: int = 0,
+                          interpret: Optional[bool] = None,
+                          tile: int = TILE, rows: Optional[int] = None):
     """Fused digest-and-compare: uint32 words (C, W) + previous digests
     (C, 4) -> (digests uint32 (C, 4), dirty uint32 (C,)).
 
     dirty[c] == 1 iff digest[c] differs from prev[c] in any lane.  Rows
     whose previous digest is unknown must be forced dirty by the caller
     (the kernel compares against whatever sentinel was supplied).
+    `interpret` defaults to the platform's mode (`pallas_interpret`),
+    `rows` to `block_rows(C)`.
     """
+    if interpret is None:
+        interpret = pallas_interpret()
+    if rows is None:
+        rows = block_rows(words.shape[0])
     words, lengths2d, C, Cp, Wp = _pad_grid(words, lengths, tile, rows)
     prev = jnp.asarray(prev, jnp.uint32)
     if Cp != C:
@@ -150,9 +193,9 @@ def fingerprint_words_cmp(words: jnp.ndarray, lengths: jnp.ndarray,
 
 @functools.partial(jax.jit,
                    static_argnames=("seed", "interpret", "tile", "rows"))
-def fingerprint_words(words: jnp.ndarray, lengths: jnp.ndarray, *,
-                      seed: int = 0, interpret: bool = True,
-                      tile: int = TILE, rows: int = 1) -> jnp.ndarray:
+def fingerprint_words(words, lengths, *, seed: int = 0,
+                      interpret: Optional[bool] = None, tile: int = TILE,
+                      rows: Optional[int] = None) -> jnp.ndarray:
     """Digest uint32 words (C, W) -> uint32 (C, 4) via the Pallas kernel.
 
     W is padded to a multiple of `tile` and C to a multiple of `rows`
@@ -160,7 +203,13 @@ def fingerprint_words(words: jnp.ndarray, lengths: jnp.ndarray, *,
     separately — see ref.py; padding rows are sliced off the output).
     `rows` chunks share one grid cell — the batched planner uses this to
     amortize dispatch across every chunk of every leaf in a bucket.
+    `interpret` defaults to the platform's mode (`pallas_interpret`),
+    `rows` to `block_rows(C)`.
     """
+    if interpret is None:
+        interpret = pallas_interpret()
+    if rows is None:
+        rows = block_rows(words.shape[0])
     words, lengths2d, C, Cp, Wp = _pad_grid(words, lengths, tile, rows)
 
     grid = (Cp // rows, Wp // tile)

@@ -15,9 +15,12 @@ runs the batched engine in `batch.py`.  Layout and contract:
     power-of-two word-width bucket (`pow2ceil(words_per_chunk)`, min 128
     words).  Rows are bucket-major: buckets ascend by width, a leaf's
     chunks are consecutive rows within its bucket.  Row counts are padded
-    to the next power of two so (C, W) bucket shapes repeat across saves
-    and the kernel jit cache stops recompiling; padded rows carry zero
-    words and a zero folded length and are sliced off on the host.
+    to a multiple of the kernel's row block; (C, W) bucket shapes depend
+    only on the leaf specs, so they repeat across saves and the kernel
+    jit cache stops recompiling; padded rows carry zero words and a zero
+    folded length and are sliced off on the host.
+  * Kernel mode: compiled on a TPU, interpreted on the CPU, decided in
+    one place from the platform (`fingerprint.pallas_interpret`).
   * Digest-neutral padding: zero words contribute nothing to the
     weighted sums and each row folds its own true byte length (ref.py),
     so a 2048-word chunk digests bit-identically whether it sits in a
@@ -71,35 +74,50 @@ from .fingerprint import fingerprint_words
 from .ref import fingerprint_words_np, fingerprint_words_ref
 
 
+#: lane count of a TPU vreg row: sub-word dtypes are packed in rows of
+#: this many words, so no intermediate has a narrow minor dimension
+_LANES = 128
+
+
 def to_words(arr: jnp.ndarray) -> jnp.ndarray:
     """Bitcast any-dtype array to a flat uint32 word stream (device-side).
 
     itemsize 4 → direct bitcast; 2 → pack pairs little-endian; 1 → pack
     quads; 8 → bitcast to 2×uint32.  Trailing bytes are zero-padded (the
-    digest folds true lengths separately)."""
+    digest folds true lengths separately).
+
+    Sub-word dtypes are packed lane-dense: the stream is laid out in rows
+    of 128 words' worth of elements, and element j of each word is the
+    strided lane slice ``[:, j::per_word]``.  A ``(-1, 2)`` or ``(-1, 4)``
+    layout would be tiled out to 128 lanes on a TPU — a 64x (resp. 32x)
+    temporary of the leaf."""
     if arr.dtype == jnp.bool_:
         arr = arr.astype(jnp.uint8)
     flat = arr.reshape(-1)
     isz = np.dtype(arr.dtype).itemsize
     if isz == 4:
         return jax.lax.bitcast_convert_type(flat, jnp.uint32)
+    if isz not in (1, 2, 8):
+        raise ValueError(f"unsupported itemsize {isz}")
+    n_words = -(-flat.shape[0] * isz // 4)
+    per = max(1, 4 // isz)              # elements per word (1 for 8-byte)
+    row = _LANES * per if isz < 4 else _LANES
+    pad = (-flat.shape[0]) % row
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
     if isz == 8:
-        return jax.lax.bitcast_convert_type(flat, jnp.uint32).reshape(-1)
-    if isz == 2:
-        u16 = jax.lax.bitcast_convert_type(flat, jnp.uint16)
-        if u16.shape[0] % 2:
-            u16 = jnp.pad(u16, (0, 1))
-        u16 = u16.reshape(-1, 2).astype(jnp.uint32)
-        return u16[:, 0] | (u16[:, 1] << jnp.uint32(16))
-    if isz == 1:
-        u8 = jax.lax.bitcast_convert_type(flat, jnp.uint8)
-        pad = (-u8.shape[0]) % 4
-        if pad:
-            u8 = jnp.pad(u8, (0, pad))
-        u8 = u8.reshape(-1, 4).astype(jnp.uint32)
-        return (u8[:, 0] | (u8[:, 1] << jnp.uint32(8))
-                | (u8[:, 2] << jnp.uint32(16)) | (u8[:, 3] << jnp.uint32(24)))
-    raise ValueError(f"unsupported itemsize {isz}")
+        words = jax.lax.bitcast_convert_type(flat.reshape(-1, row),
+                                             jnp.uint32)
+        return words.reshape(-1)[:n_words]
+    u = jax.lax.bitcast_convert_type(
+        flat, jnp.uint16 if isz == 2 else jnp.uint8).reshape(-1, row)
+    # slice before widening: the strided slices of the narrow dtype are
+    # the only temporaries (together the size of the leaf)
+    words = u[:, 0::per].astype(jnp.uint32)
+    for j in range(1, per):
+        words = words | (u[:, j::per].astype(jnp.uint32)
+                         << jnp.uint32(8 * isz * j))
+    return words.reshape(-1)[:n_words]
 
 
 def to_words_np(arr: np.ndarray) -> np.ndarray:
@@ -115,8 +133,7 @@ def to_words_np(arr: np.ndarray) -> np.ndarray:
 
 
 def leaf_fingerprint(arr: Any, *, chunk_bytes: int = 1 << 22, seed: int = 0,
-                     use_kernel: bool = True, interpret: bool = True
-                     ) -> np.ndarray:
+                     use_kernel: bool = True) -> np.ndarray:
     """Digest one array on its flat-range chunk grid → uint32 (n_chunks, 4)."""
     arr = jnp.asarray(arr)
     shape = tuple(int(d) for d in arr.shape)
@@ -140,7 +157,7 @@ def leaf_fingerprint(arr: Any, *, chunk_bytes: int = 1 << 22, seed: int = 0,
         lengths = jnp.asarray(lens)
 
     if use_kernel:
-        dig = fingerprint_words(words, lengths, seed=seed, interpret=interpret)
+        dig = fingerprint_words(words, lengths, seed=seed)
     else:
         dig = fingerprint_words_ref(words, lengths, seed=seed)
     return np.asarray(jax.device_get(dig))
@@ -176,8 +193,7 @@ def digest_to_bytes(row: np.ndarray) -> bytes:
 
 def tree_fingerprint(graph: ObjectGraph, *, active_leaf_paths=None,
                      chunk_bytes: int = 1 << 22, seed: int = 0,
-                     use_kernel: bool = True, interpret: bool = True
-                     ) -> Dict[str, bytes]:
+                     use_kernel: bool = True) -> Dict[str, bytes]:
     """Digest every chunk of (active) leaves → {chunk key: 16-byte digest}."""
     out: Dict[str, bytes] = {}
     for leaf in graph.leaf_nodes():
@@ -189,7 +205,7 @@ def tree_fingerprint(graph: ObjectGraph, *, active_leaf_paths=None,
             dig = leaf_fingerprint_np(arr, chunk_bytes=chunk_bytes, seed=seed)
         else:
             dig = leaf_fingerprint(arr, chunk_bytes=chunk_bytes, seed=seed,
-                                   use_kernel=use_kernel, interpret=interpret)
+                                   use_kernel=use_kernel)
         for ci in range(dig.shape[0]):
             out[f"{lkey}#[{ci}]"] = digest_to_bytes(dig[ci])
     return out

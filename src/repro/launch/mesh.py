@@ -8,11 +8,6 @@ The `pod` axis maps to the cross-pod DCI domain and carries only gradient
 reduction; `model` stays inside an ICI axis.  Defined as functions (never
 module-level constants) so importing this module never touches jax device
 state — the dry-run forces 512 host devices before first jax init.
-
-jax compat: `jax.sharding.AxisType` only exists in newer jax releases
-(explicit-sharding work); on older installs (e.g. 0.4.x) meshes are
-implicitly Auto-typed, so the shim below simply drops the kwarg.  Use
-`make_mesh_compat` instead of touching `AxisType` directly.
 """
 from __future__ import annotations
 
@@ -20,29 +15,14 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
-def axis_types_kwargs(n_axes: int) -> dict:
-    """`axis_types=(AxisType.Auto,) * n` where supported, else nothing
-    (older jax treats every mesh axis as Auto implicitly)."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
-
-
-def make_mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...],
-                     devices: Optional[Sequence] = None) -> Mesh:
-    """`jax.make_mesh` with Auto axis types on any installed jax."""
-    kw = axis_types_kwargs(len(axes))
-    if devices is not None:
-        kw["devices"] = devices
-    return jax.make_mesh(shape, axes, **kw)
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """`jax.make_mesh` with every axis Auto-typed."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -54,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devices)} — run via "
             "launch/dryrun.py (forces --xla_force_host_platform_device_count=512)")
-    return make_mesh_compat(shape, axes, devices=devices[:n])
+    return make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_local_mesh() -> Mesh:
@@ -67,7 +47,7 @@ def make_local_mesh() -> Mesh:
         if n % m == 0:
             model = m
             break
-    return make_mesh_compat((n // model, model), ("data", "model"),
+    return make_mesh((n // model, model), ("data", "model"),
                             devices=devices)
 
 

@@ -2,7 +2,7 @@
 incremental persistence over one shared store.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
-        --reduced --sessions 4 --store memory
+        --sessions 4 --store memory [--no-reduced]
 
 Serving state (KV caches / SSM states + request cursors) is a massive,
 evolving, append-mostly object graph — Chipmink's best case: between
@@ -36,6 +36,7 @@ from ..core import FileStore, LGA, MemoryStore
 from ..models.model import api, init_model_params
 from ..sessions import SessionService
 from ..train.serve_step import make_decode_step
+from .compile_cache import enable_compile_cache
 
 
 def _make_store(store: str, store_dir: Optional[str]):
@@ -57,7 +58,8 @@ def serve(arch: str, *, n_requests: int = 4, gen_tokens: int = 32,
     ``n_requests`` requests each, snapshotting every session every
     ``save_every`` tokens onto its own branch of one shared store.
     Returns tokens, per-snapshot stats (TimeID order), the service, and
-    the fleet roll-up (dedup ratio, save-stall percentiles)."""
+    the fleet roll-up (dedup ratio, save-stall percentiles), and every
+    session's live cache after its last token."""
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
@@ -146,15 +148,21 @@ def serve(arch: str, *, n_requests: int = 4, gen_tokens: int = 32,
            if sessions[0].generated else np.zeros((n_requests, 0)))
     return {"tokens": out, "chipmink": svc.pool[0], "service": svc,
             "snap_stats": snap_stats, "fleet": fleet.as_dict(),
-            "evict_stats": evict_stats, "wall": wall}
+            "evict_stats": evict_stats, "wall": wall,
+            "caches": {f"s{s}": sess.cache
+                       for s, sess in enumerate(sessions)}}
 
 
 def main() -> None:
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen1.5-0.5b")
     p.add_argument("--requests", type=int, default=4)
     p.add_argument("--gen-tokens", type=int, default=32)
-    p.add_argument("--reduced", action="store_true", default=True)
+    p.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                   default=True,
+                   help="cut the config to CPU-test size "
+                        "(--no-reduced: published widths)")
     p.add_argument("--sessions", type=int, default=1,
                    help="concurrent sessions sharing the store")
     p.add_argument("--store", choices=("memory", "file"), default="memory",

@@ -1,10 +1,10 @@
 """End-to-end training driver with Chipmink incremental checkpointing.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \
-        --reduced --steps 50 --save-every 10 --store /tmp/ck
+        --steps 50 --save-every 10 --store ck_store [--no-reduced]
 
-Runs real training (CPU: reduced configs; TPU fleet: full configs under
-the production mesh), saving through Chipmink every `save_every` steps:
+Runs real training (reduced configs by default; ``--no-reduced`` runs
+the published widths), saving through Chipmink every `save_every` steps:
 the step's touch report (frozen masks, MoE expert counts) drives the
 active-variable filter, the jaxpr ASCC proves frozen leaves read-only,
 and the data-pipeline cursor rides along as host state.
@@ -27,6 +27,7 @@ from ..train.data import TokenPipeline
 from ..train.optimizer import OptConfig
 from ..train.train_step import (init_train_state, make_train_step,
                                 touched_prefixes_from_metrics)
+from .compile_cache import enable_compile_cache
 
 
 def snapshot_of(state: Dict, pipeline: TokenPipeline) -> Dict:
@@ -49,9 +50,14 @@ def train(arch: str, *, steps: int = 50, save_every: int = 10,
     params = init_model_params(cfg, jax.random.key(0))
     state = init_train_state(cfg, params, opt_cfg, grad_compress=grad_compress)
     pipeline = TokenPipeline(cfg.vocab, global_batch, seq_len)
-    step_fn = jax.jit(make_train_step(
-        cfg, opt_cfg, frozen=frozen, grad_compress=grad_compress,
-        remat=False))
+    step = make_train_step(cfg, opt_cfg, frozen=frozen,
+                           grad_compress=grad_compress, remat=False)
+    # A step donates its input state, except the step right after a
+    # save: Chipmink keeps that snapshot (the save body reads it, and
+    # checkout hands its arrays back afterwards), so it must outlive the
+    # step.  Donation keeps one state copy fewer on the device.
+    step_keep = jax.jit(step)
+    step_donate = jax.jit(step, donate_argnums=0)
 
     store = FileStore(store_dir) if store_dir else MemoryStore()
     ck = Chipmink(store, LGA(), chunk_bytes=1 << 18, async_mode=async_save)
@@ -60,18 +66,20 @@ def train(arch: str, *, steps: int = 50, save_every: int = 10,
     example = pipeline.next_batch()
     example = {k: jnp.asarray(v) for k, v in example.items()}
     pipeline.restore({**pipeline.cursor(), "step": 0})
-    readonly = readonly_state_leaves(step_fn, state, example)
+    readonly = readonly_state_leaves(step_keep, state, example)
     readonly = {"params/" + p if not p.startswith(("params", "opt", "step"))
                 else p for p in readonly}
 
     losses: List[float] = []
     t_start = time.time()
     metrics: Dict = {}
+    saved = False
     for i in range(steps):
         batch = {k: jnp.asarray(v) for k, v in pipeline.next_batch().items()}
-        state, metrics = step_fn(state, batch)
+        state, metrics = (step_keep if saved else step_donate)(state, batch)
         losses.append(float(metrics["nll"]))
-        if (i + 1) % save_every == 0 or i + 1 == steps:
+        saved = (i + 1) % save_every == 0 or i + 1 == steps
+        if saved:
             touched = touched_prefixes_from_metrics(cfg, metrics, frozen)
             tid = ck.save(snapshot_of(state, pipeline),
                           touched_prefixes=touched,
@@ -95,12 +103,16 @@ def train(arch: str, *, steps: int = 50, save_every: int = 10,
 
 
 def main() -> None:
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="qwen1.5-0.5b")
     p.add_argument("--steps", type=int, default=50)
     p.add_argument("--save-every", type=int, default=10)
     p.add_argument("--store", default=None)
-    p.add_argument("--reduced", action="store_true", default=True)
+    p.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                   default=True,
+                   help="cut the config to CPU-test size "
+                        "(--no-reduced: published widths)")
     p.add_argument("--batch", type=int, default=8)
     p.add_argument("--seq", type=int, default=128)
     p.add_argument("--frozen", nargs="*", default=[])

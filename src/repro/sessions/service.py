@@ -169,8 +169,8 @@ class SessionService:
                                                   Optional[FlipTracker]]:
         d = ck.detector
         det = ChangeDetector(chunk_bytes=d.chunk_bytes, seed=d.seed,
-                             use_kernel=d.use_kernel, interpret=d.interpret,
-                             batched=d.batched, fused=d.fused)
+                             use_kernel=d.use_kernel, batched=d.batched,
+                             fused=d.fused)
         cache = (GraphCache(chunk_bytes=ck.chunk_bytes)
                  if ck.incremental else None)
         tracker = FlipTracker() if ck.tracker is not None else None

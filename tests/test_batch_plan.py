@@ -64,8 +64,9 @@ def test_plan_partitions_every_chunk(n_leaves, seed, chunk):
     for b in plan.buckets:
         rows = {r for (w, r) in seen if w == b.width}
         assert rows == set(range(b.n_rows))
-        assert b.padded_rows == pow2ceil(b.n_rows)
+        # rows pad to the next multiple of the block, not a power of two
         assert b.padded_rows % b.block_rows == 0
+        assert 0 <= b.padded_rows - b.n_rows < b.block_rows
 
     # true byte lengths preserved: sum of folded lengths == payload bytes
     from repro.kernels.batch import _plan_lengths
@@ -95,10 +96,10 @@ def test_batched_matches_per_leaf_oracle_device(dt):
     arrs = [jnp.asarray(_rand_leaf(rng, r, c, dt))
             for r, c in [(57, 3), (300, 8), (1, 1)]]
     items = [(f"l{i}", a) for i, a in enumerate(arrs)]
-    res = digest_leaves(items, chunk_bytes=512, seed=5, interpret=True)
+    res = digest_leaves(items, chunk_bytes=512, seed=5)
     assert res.n_syncs == 1           # single end-of-save digest fetch
     for i, a in enumerate(arrs):
-        oracle = leaf_fingerprint(a, chunk_bytes=512, seed=5, interpret=True)
+        oracle = leaf_fingerprint(a, chunk_bytes=512, seed=5)
         r0 = res.leaf_rows[f"l{i}"]
         got = res.mat[r0:r0 + oracle.shape[0]]
         assert (got == oracle).all()
@@ -125,6 +126,54 @@ def test_bucket_shapes_stable_across_saves():
     packers and kernel shapes are reused save-over-save."""
     specs = (("a", (128, 4), "float32"), ("b", (9, 9), "bfloat16"))
     assert plan_leaves(specs, 1 << 10) is plan_leaves(specs, 1 << 10)
+
+
+@pytest.mark.parametrize("n_rows", [1, 3, 64, 65, 17695])
+def test_padded_rows_follow_block_rows(n_rows):
+    """A bucket pads its rows to the next multiple of its row block (not
+    to a power of two), and the device-resident digest table takes that
+    padded shape."""
+    from repro.kernels.batch import seed_device_table
+    plan = plan_leaves((("w", (n_rows, 128), "float32"),), 512)
+    (b,) = plan.buckets
+    assert b.n_rows == n_rows
+    assert b.block_rows == min(64, pow2ceil(n_rows))
+    assert b.padded_rows == -(-n_rows // b.block_rows) * b.block_rows
+    table = seed_device_table(plan, lambda key: None)
+    assert table.digs[0].shape == (b.padded_rows, 4)
+    assert table.valid[0].shape == (n_rows,)
+
+
+def test_fused_table_keeps_padded_shape():
+    from repro.kernels.batch import digest_leaves_fused
+    items = [("w", jnp.ones((65, 128), jnp.float32))]
+    res, table = digest_leaves_fused(items, chunk_bytes=512)
+    (b,) = table.plan.buckets
+    assert (b.n_rows, b.padded_rows) == (65, 128)
+    assert table.digs[0].shape == (128, 4)
+    assert res.mat.shape == (65, 4) and (res.dirty == 1).all()
+
+
+@pytest.mark.parametrize("shape,dt", [((1000, 8), "float32"),
+                                      ((1000, 16), "bfloat16"),
+                                      ((700, 4, 8), "int8"),
+                                      ((5000,), "float32")])
+def test_packer_pieces_match_host_twin(shape, dt):
+    """Leaves cut into row pieces (more than PACK_PIECE_CHUNKS chunks of
+    whole rows, last chunk partial) pack to the host twin's exact words."""
+    from repro.kernels.batch import (PACK_PIECE_CHUNKS, _pack_leaf_words_np,
+                                     _packer_for)
+    rng = np.random.default_rng(len(shape))
+    host = _rand_leaf(rng, int(np.prod(shape[:-1])), shape[-1], dt)
+    host = host.reshape(shape)
+    plan = plan_leaves((("x", shape, dt), ("y", (5, 3), "float32")), 512)
+    slot = plan.leaves[0]
+    assert slot.n_chunks > PACK_PIECE_CHUNKS
+    packed = _packer_for(plan)(jnp.asarray(host),
+                               jnp.ones((5, 3), jnp.float32))
+    bi = [b.width for b in plan.buckets].index(slot.bucket)
+    got = np.asarray(packed[bi])[slot.row0:slot.row0 + slot.n_chunks]
+    assert np.array_equal(got, _pack_leaf_words_np(slot, host))
 
 
 def test_detector_single_sync_per_save():

@@ -143,7 +143,7 @@ def test_two_processes_race_saves_and_branches(tmp_path):
     boot.close()
 
     n_saves = 4
-    env = dict(os.environ, PYTHONPATH=SRC_DIR)
+    env = dict(os.environ, PYTHONPATH=SRC_DIR, JAX_PLATFORMS="cpu")
     procs = [subprocess.Popen(
         [sys.executable, "-c", WORKER, root, str(idx), str(n_saves)],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE)

@@ -93,13 +93,15 @@ def _leaves(rng, n, dtypes=("float32", "bfloat16", "int8")):
 
 
 @given(n=integers(1, 5), seed=integers(0, 10_000),
-       chunk=sampled_from([256, 1024]))
-def test_fused_engine_parity_and_payload(n, seed, chunk):
+       chunk=sampled_from([256, 1024]), frac=sampled_from([0.25, 0.5, 1.0]))
+def test_fused_engine_parity_and_payload(n, seed, chunk, frac):
+    """`frac` of the chunks speculated: leaves speculated at least half
+    over are fetched whole, the others gathered row by row."""
     rng = np.random.default_rng(seed)
     items = _leaves(rng, n)
     base = digest_leaves(items, chunk_bytes=chunk)
     all_keys = set(base.keys)
-    spec = {k for k in all_keys if rng.random() < 0.5}
+    spec = {k for k in all_keys if rng.random() < frac}
     res, table = digest_leaves_fused(
         items, chunk_bytes=chunk, lookup=lambda k: None, spec_keys=spec)
     assert res.keys == base.keys
@@ -134,6 +136,94 @@ def test_fused_engine_host_rows_unknown():
     dirty = {k: int(d) for k, d in zip(res.keys, res.dirty)}
     assert dirty["dev#[0]"] == 1          # device row, no prev: dirty
     assert dirty["host#[0]"] == -1        # host row: caller decides
+
+
+def test_half_speculated_leaf_crosses_whole(monkeypatch):
+    """Each leaf is judged on its own speculated chunks: one speculated
+    at least half over crosses as the array itself (no device copy),
+    one speculated sparsely crosses as gathered packed rows, and one
+    with no speculated chunk does not cross at all."""
+    items = [("a", jnp.arange(8 * 256, dtype=jnp.float32)),
+             ("b", jnp.ones(4 * 256, jnp.float32)),
+             ("c", jnp.arange(8 * 256, dtype=jnp.float32) * 3),
+             ("d", jnp.zeros(4 * 256, jnp.float32))]
+    fetched = []
+    real = jax.device_get
+
+    def spy(x):
+        fetched.extend(jax.tree.leaves(x))
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", spy)
+    spec = {f"a#[{i}]" for i in range(8)} | {"b#[0]", "b#[1]", "c#[5]"}
+    res, table = digest_leaves_fused(items, chunk_bytes=1 << 10,
+                                     spec_keys=spec)
+    (b,) = table.plan.buckets
+    sent = [k for k, x in items if any(a is x for a in fetched)]
+    assert sent == ["a", "b"]
+    assert not any(a.shape == (b.padded_rows, b.width) for a in fetched)
+    assert sum(a.shape == (1, b.width) for a in fetched) == 1   # c#[5]
+    assert set(res.payload) == spec
+    graph = build_graph(dict(items), chunk_bytes=1 << 10)
+    for node in graph.chunk_nodes():
+        if node.key in spec:
+            arr = np.asarray(graph.arrays[path_str(node.path)])
+            assert res.payload[node.key] == chunk_slice(arr, node).tobytes()
+
+
+def test_dense_gather_fetches_whole_leaves(monkeypatch):
+    """A leaf with at least half its chunks requested crosses as the
+    array itself (no device copy); a sparsely requested leaf is sliced
+    per chunk.  Bytes are the chunk payloads either way."""
+    from repro.core.podding import batched_chunk_fetch
+    dense = jnp.arange(8 * 256, dtype=jnp.float32)
+    sparse = jnp.arange(8 * 256, dtype=jnp.float32) * 2
+    graph = build_graph({"dense": dense, "sparse": sparse},
+                        chunk_bytes=1 << 10)
+    chunks = {n.key: n for n in graph.chunk_nodes()}
+    want = [k for k in chunks if k.startswith("dense")] + \
+        ["sparse#[1]", "sparse#[6]"]
+    fetched_args = []
+    real = jax.device_get
+
+    def spy(x):
+        fetched_args.extend(x)
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", spy)
+    get, n_syncs = batched_chunk_fetch(graph, [chunks[k] for k in want])
+    assert n_syncs == 1
+    assert any(a is graph.arrays["dense"] for a in fetched_args)
+    assert len(fetched_args) == 3      # dense whole + 2 sparse slices
+    for key in want:
+        node = chunks[key]
+        arr = np.asarray(graph.arrays[path_str(node.path)])
+        assert get(node) == chunk_slice(arr, node).tobytes()
+
+
+def test_save_body_drops_older_snapshot():
+    """Once a newer save's body runs, the instance no longer references
+    the arrays of the save before it: only the newest snapshot stays on
+    the device."""
+    import gc
+    import weakref
+
+    ck = Chipmink(MemoryStore(), chunk_bytes=1 << 10)
+    first = jnp.arange(4096, dtype=jnp.float32)
+    ck.save({"w": first})
+    ref = weakref.ref(first)
+    del first
+    seen = []
+    detect = ck.detector.detect
+
+    def spy(*args, **kw):
+        gc.collect()
+        seen.append(ref() is None)
+        return detect(*args, **kw)
+
+    ck.detector.detect = spy
+    ck.save({"w": jnp.zeros(4096, jnp.float32)})
+    assert seen == [True]
 
 
 # --------------------------------------------------------------------------

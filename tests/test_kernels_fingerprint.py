@@ -59,6 +59,25 @@ def test_words_conversion_device_host_parity(dt):
     assert (w1 == w2).all()
 
 
+@pytest.mark.parametrize("n", [1, 3, 255, 257, 1001])
+@pytest.mark.parametrize("dt", ["bool", "int8", "uint8", "bfloat16",
+                                "float16", "float32", "float64"])
+def test_lane_dense_words_bit_identical(dt, n):
+    """The lane-dense device packing equals the host twin byte for byte
+    at odd lengths and at lengths that are not multiples of 256."""
+    rng = np.random.default_rng(n)
+    x = rng.standard_normal(n) * 1000
+    x = x > 0 if dt == "bool" else x.astype(
+        jnp.bfloat16 if dt == "bfloat16" else dt)
+    with jax.enable_x64(dt == "float64"):
+        dev = jnp.asarray(x)
+        assert dev.dtype == x.dtype
+        got = np.asarray(to_words(dev))
+    want = to_words_np(x)
+    assert got.dtype == np.uint32 and got.shape == want.shape
+    assert (got == want).all()
+
+
 @pytest.mark.slow
 @given(rows=integers(1, 700), cols=integers(1, 9),
        dt=sampled_from(["float32", "float16", "int8"]),

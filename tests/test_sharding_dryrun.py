@@ -7,7 +7,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.dryrun import parse_collectives
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import (batch_spec, set_rule_overrides,
                                      spec_for, tree_shardings)
 
@@ -15,7 +15,7 @@ from repro.parallel.sharding import (batch_spec, set_rule_overrides,
 @pytest.fixture(scope="module")
 def mesh():
     # 1-device mesh with the production axis names (sizes 1 → rules drop)
-    return make_mesh_compat((1, 1), ("data", "model"),
+    return make_mesh((1, 1), ("data", "model"),
                             devices=jax.devices()[:1])
 
 

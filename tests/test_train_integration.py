@@ -164,13 +164,12 @@ def test_grad_quantization_error_feedback():
 
 
 def test_compressed_psum_shardmap():
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("d",), devices=jax.devices()[:1])
-    from jax.experimental.shard_map import shard_map
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("d",), devices=jax.devices()[:1])
     from jax.sharding import PartitionSpec as P
     x = jnp.asarray(np.random.default_rng(0).standard_normal(256),
                     jnp.float32)
-    f = shard_map(lambda v: compressed_psum(v, "d"), mesh=mesh,
+    f = jax.shard_map(lambda v: compressed_psum(v, "d"), mesh=mesh,
                   in_specs=P(), out_specs=P())
     y = f(x)
     rel = float(jnp.linalg.norm(y - x) / jnp.linalg.norm(x))
