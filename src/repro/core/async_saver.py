@@ -21,7 +21,10 @@ be in flight — one running on the worker plus ``depth - 1`` queued — so
 save N's decide/gather/write overlaps step N+1's compute.  Submitting
 while the pipeline is full blocks until a slot frees (backpressure), and
 each such block is counted in ``n_stalls``; a caller whose previous save
-finishes before the next ``save()`` therefore observes zero stalls.
+finishes before the next ``save()`` therefore observes zero stalls.  A
+submit may pass a `QueueTimes`, which the saver fills under its
+condition variable: the caller's wait for a free slot, then the body's
+wait in the queue, both stamped before the body starts.
 Save *bodies* still execute strictly FIFO on one worker thread, which is
 what keeps the cross-save state (digest table, previous PodAssignment,
 thesaurus) free of write races; the caller-side snapshot (graph build at
@@ -37,9 +40,11 @@ drain so supervision code can account for absorbed failures.
 """
 from __future__ import annotations
 
+import dataclasses
 import threading
+import time
 from collections import deque
-from typing import Any, Callable, Deque, List, Optional
+from typing import Any, Callable, Deque, List, Optional, Tuple
 
 
 class AsyncSaveError(RuntimeError):
@@ -59,13 +64,25 @@ class AsyncSaveError(RuntimeError):
         super().__init__(f"{len(self.errors)} async saves failed: {msg}")
 
 
+@dataclasses.dataclass
+class QueueTimes:
+    """Where one submitted body waited, in seconds.  Both fields are
+    written under the saver's condition variable before the body starts,
+    so the body may read them."""
+
+    submit_wait: float = 0.0    # caller blocked in submit for a free slot
+    queue: float = 0.0          # enqueue -> start on the podding thread
+    enqueued: float = 0.0       # perf_counter() at the enqueue
+
+
 class AsyncSaver:
     def __init__(self, depth: int = 2) -> None:
         self.l_ns = threading.Lock()        # namespace lock
         self.l_active = threading.Lock()    # active-variable lock
         self.depth = max(1, int(depth))     # max saves in flight
         self._cv = threading.Condition()
-        self._queue: Deque[Callable[[], Any]] = deque()
+        self._queue: Deque[Tuple[Callable[[], Any],
+                                 Optional[QueueTimes]]] = deque()
         self._inflight = 0                  # queued + running
         self._worker: Optional[threading.Thread] = None
         self._errors: List[BaseException] = []
@@ -120,10 +137,12 @@ class AsyncSaver:
             errs, self._errors = self._errors, []
             return errs
 
-    def submit(self, fn: Callable[[], Any]) -> None:
+    def submit(self, fn: Callable[[], Any],
+               times: Optional[QueueTimes] = None) -> None:
         """Enqueue `fn` on the podding thread.  Returns immediately while
         fewer than `depth` saves are in flight; otherwise blocks until the
-        oldest save retires (backpressure, counted in `n_stalls`).
+        oldest save retires (backpressure, counted in `n_stalls`, timed
+        into `times.submit_wait`).
 
         Previously failed saves surface here (as they did when submit
         joined the prior thread): the pending errors re-raise (combined
@@ -137,11 +156,17 @@ class AsyncSaver:
             self.n_submits += 1
             if self._inflight > 0:
                 self.n_overlapped += 1
+            waited = 0.0
             if self._inflight >= self.depth:
                 self.n_stalls += 1
+                t0 = time.perf_counter()
                 while self._inflight >= self.depth:
                     self._cv.wait()
-            self._queue.append(fn)
+                waited = time.perf_counter() - t0
+            if times is not None:
+                times.submit_wait = waited
+                times.enqueued = time.perf_counter()
+            self._queue.append((fn, times))
             self._inflight += 1
             if self._worker is None:
                 self._worker = threading.Thread(
@@ -157,7 +182,9 @@ class AsyncSaver:
                     self._worker = None
                     self._cv.notify_all()
                     return
-                fn = self._queue.popleft()
+                fn, times = self._queue.popleft()
+                if times is not None:
+                    times.queue = time.perf_counter() - times.enqueued
             try:
                 with self.l_active:
                     fn()

@@ -41,6 +41,7 @@ import numpy as np
 from ..kernels import batch as kbatch
 from ..kernels import ops as kops
 from .graph import CHUNK, Node, ObjectGraph
+from .phases import NO_PHASES
 
 
 def pack_digest_table(digests: Dict[str, bytes]) -> Dict[str, bytes]:
@@ -74,6 +75,7 @@ class ChangeReport:
     active_chunks: int = 0
     skipped_chunks: int = 0
     n_syncs: int = 0                   # blocking device fetches this save
+    dirty_bytes: int = 0               # true lengths of the dirty chunks
     #: speculatively prefetched payload bytes (chunk key -> exact bytes),
     #: compacted into the digest fetch by the fused path
     payload: Dict[str, bytes] = dataclasses.field(default_factory=dict)
@@ -148,7 +150,7 @@ class ChangeDetector:
         return self._table[i].tobytes()
 
     def _digest(self, leaves: List[Node], graph: ObjectGraph,
-                speculate: Optional[Set[str]] = None
+                speculate: Optional[Set[str]] = None, phases=NO_PHASES
                 ) -> kbatch.DigestResult:
         """Digest all chunks of `leaves` → slot-ordered DigestResult.
 
@@ -159,17 +161,25 @@ class ChangeDetector:
         device leaf.
         """
         items = [(leaf.key, graph.arrays[leaf.key]) for leaf in leaves]
-        if self.fused:
-            res, self._dev_table = kbatch.digest_leaves_fused(
-                items, chunk_bytes=self.chunk_bytes, seed=self.seed,
-                use_kernel=self.use_kernel,
-                table=self._dev_table, lookup=self._lookup_digest,
-                spec_keys=speculate)
+        if self.fused or self.batched:
+            with phases("digest.dispatch"):
+                if self.fused:
+                    pending = kbatch.dispatch_leaves_fused(
+                        items, chunk_bytes=self.chunk_bytes, seed=self.seed,
+                        use_kernel=self.use_kernel,
+                        table=self._dev_table, lookup=self._lookup_digest,
+                        spec_keys=speculate)
+                else:
+                    pending = kbatch.dispatch_leaves(
+                        items, chunk_bytes=self.chunk_bytes, seed=self.seed,
+                        use_kernel=self.use_kernel)
+            with phases("digest.fetch"):
+                phases.add("bytes_fetched", pending.fetch())
+            with phases("digest.unpack"):
+                res = pending.finish()
+            if self.fused:
+                res, self._dev_table = res
             return res
-        if self.batched:
-            return kbatch.digest_leaves(
-                items, chunk_bytes=self.chunk_bytes, seed=self.seed,
-                use_kernel=self.use_kernel)
         keys: List[str] = []
         mats: List[np.ndarray] = []
         leaf_rows: Dict[str, int] = {}
@@ -180,9 +190,11 @@ class ChangeDetector:
                 dig = kops.leaf_fingerprint_np(
                     arr, chunk_bytes=self.chunk_bytes, seed=self.seed)
             else:
-                dig = kops.leaf_fingerprint(
-                    arr, chunk_bytes=self.chunk_bytes, seed=self.seed,
-                    use_kernel=self.use_kernel)
+                with phases("digest.fetch"):
+                    dig = kops.leaf_fingerprint(
+                        arr, chunk_bytes=self.chunk_bytes, seed=self.seed,
+                        use_kernel=self.use_kernel)
+                phases.add("bytes_fetched", dig.nbytes)
                 n_syncs += 1
             leaf_rows[lkey] = row
             keys.extend(f"{lkey}#[{ci}]" for ci in range(dig.shape[0]))
@@ -196,7 +208,11 @@ class ChangeDetector:
     # ------------------------------------------------------------------
     def detect(self, graph: ObjectGraph,
                active_leaf_paths: Optional[Set[str]] = None,
-               speculate: Optional[Set[str]] = None) -> ChangeReport:
+               speculate: Optional[Set[str]] = None,
+               phases=NO_PHASES) -> ChangeReport:
+        """Digest the active chunks and diff them against the previous
+        table.  `phases` (`core.phases.Phases`) times the digest's
+        sub-phases and counts the bytes fetched from the device."""
         # 1. choose the leaves to digest: every active leaf, plus any
         # inactive leaf with chunks the table has never seen (those must
         # be digested now; their already-seen siblings still inherit).
@@ -210,7 +226,12 @@ class ChangeDetector:
             elif self._seen_leaves.get(lkey) != len(leaf.children):
                 digest_leaves.append(leaf)
 
-        res = self._digest(digest_leaves, graph, speculate)
+        res = self._digest(digest_leaves, graph, speculate, phases)
+        with phases("digest.diff"):
+            return self._diff(graph, res, active_leaf_set)
+
+    def _diff(self, graph: ObjectGraph, res: kbatch.DigestResult,
+              active_leaf_set: Set[str]) -> ChangeReport:
         C = len(res.keys)
 
         # 2. dirtiness per slot row.  Fused path: the kernel already
@@ -245,6 +266,7 @@ class ChangeDetector:
         # fallback-digested leaf is used and the chunk is dirty).
         digests: Dict[str, bytes] = {}
         dirty: Set[str] = set()
+        dirty_bytes = 0
         new_keys: List[str] = []
         new_rows: List[int] = []        # rows into res.mat (or ~row into table)
         seen_leaves: Dict[str, int] = {}
@@ -259,6 +281,7 @@ class ChangeDetector:
                 digests[key] = buf[16 * r:16 * (r + 1)]
                 if changed[r]:
                     dirty.add(key)
+                    dirty_bytes += node.size
                 new_keys.append(key)
                 new_rows.append(r)
             else:
@@ -272,6 +295,7 @@ class ChangeDetector:
                     r = res.row_of(lkey, node.chunk_index)
                     digests[key] = buf[16 * r:16 * (r + 1)]
                     dirty.add(key)
+                    dirty_bytes += node.size
                     new_keys.append(key)
                     new_rows.append(r)
 
@@ -296,7 +320,8 @@ class ChangeDetector:
         hits = sum(1 for k in dirty if k in payload)
         return ChangeReport(digests=digests, dirty=dirty,
                             active_chunks=active, skipped_chunks=skipped,
-                            n_syncs=res.n_syncs, payload=payload,
+                            n_syncs=res.n_syncs, dirty_bytes=dirty_bytes,
+                            payload=payload,
                             n_spec_hits=hits,
                             n_spec_misses=len(dirty) - hits,
                             fused_rows=fused_rows)

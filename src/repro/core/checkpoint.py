@@ -238,7 +238,7 @@ import msgpack
 import numpy as np
 
 from .active_filter import ActiveVariableFilter
-from .async_saver import AsyncSaver
+from .async_saver import AsyncSaver, QueueTimes
 from .change_detector import ChangeDetector, pack_digest_table
 from .delta import DeltaPolicy, encode_pod_delta
 from .faults import RetryPolicy, call_with_retries
@@ -246,6 +246,7 @@ from .graph import CHUNK, ObjectGraph, build_graph, rebuild_tree
 from .graph_cache import GraphCache, IncrementalBuildInfo
 from .lease import Lease, LeaseHeartbeat, LeaseLost, LeaseManager
 from .lga import LGA, PoddingPolicy
+from .phases import Phases
 from .podding import (PodAssignment, Unpodder, batched_chunk_fetch,
                       fused_chunk_fetch, open_manifest, pod_graph,
                       pod_structural_digest, serialize_pod)
@@ -465,8 +466,52 @@ class Chipmink:
 
         # graph build runs on the caller's thread: it is the snapshot that
         # makes overlapped async saves sound (scalar values are copied into
-        # SCALAR nodes; device array references are immutable).
-        t0 = _time.perf_counter()
+        # SCALAR nodes; device array references are immutable).  The
+        # caller fills `stats` before the submit and never after it: from
+        # there on the body owns the dict.
+        stats: Dict[str, Any] = {"time_id": time_id}
+        ph = Phases(stats, time_id)
+        with ph.span("save"):
+            with ph("graph"):
+                graph, ginfo, n_leaf_copies = self._build_snapshot(
+                    state, time_id)
+            stats["n_leaf_copies"] = n_leaf_copies
+
+            times = QueueTimes() if self.async_mode else None
+
+            def work() -> None:
+                self._save_body(time_id, graph, ginfo, accessed_vars,
+                                touched_prefixes, readonly_paths, parent,
+                                stats, times, branch)
+
+            if times is not None:
+                try:
+                    # overlapped; FIFO on the podding thread.  May re-raise
+                    # a PREVIOUS save's failure, in which case THIS save is
+                    # dropped (its body never enqueued).
+                    with ph.span("submit_wait"):
+                        self.saver.submit(work, times)
+                except BaseException:
+                    # The graph cache already advanced for the dropped
+                    # save, so a later identical state would diff as
+                    # "unchanged" against a build that never persisted.
+                    # Invalidating the cache forces the next save to
+                    # rebuild (and therefore re-pod and re-hash) from
+                    # scratch; this is the only race-free reset — queued
+                    # bodies still in flight may re-arm _prev_pods /
+                    # _pod_digests after the fact, but a from-scratch
+                    # build never consults them.
+                    if self._graph_cache is not None:
+                        self._graph_cache.invalidate()
+                    raise
+            else:
+                work()
+        self._head = time_id
+        return time_id
+
+    def _build_snapshot(self, state: Any, time_id: TimeID) -> tuple:
+        """The save's graph, its incremental build info and the number of
+        host leaves copied on submit, on the caller's thread."""
         if self._graph_cache is not None:
             graph, ginfo = self._graph_cache.build(state)
         else:
@@ -501,54 +546,30 @@ class Chipmink:
             if self.large_leaf_action == "raise":
                 if self._graph_cache is not None:
                     # the cache advanced for a save that will never run —
-                    # same reset as a rejected submit below.
+                    # same reset as a rejected submit in save().
                     self._graph_cache.invalidate()
                 raise ValueError(msg)
             fresh = [k for k in large_leaves
                      if k not in self._large_leaves_warned]
             if fresh:
                 self._large_leaves_warned.update(fresh)
-                warnings.warn(msg, RuntimeWarning, stacklevel=2)
-        t_graph = _time.perf_counter() - t0
-
-        def work() -> None:
-            self._save_body(time_id, graph, ginfo, accessed_vars,
-                            touched_prefixes, readonly_paths, parent, t_graph,
-                            n_leaf_copies, branch)
-
-        if self.async_mode:
-            try:
-                # overlapped; FIFO on the podding thread.  May re-raise a
-                # PREVIOUS save's failure, in which case THIS save is
-                # dropped (its body never enqueued).
-                self.saver.submit(work)
-            except BaseException:
-                # The graph cache already advanced for the dropped save, so
-                # a later identical state would diff as "unchanged" against
-                # a build that never persisted.  Invalidating the cache
-                # forces the next save to rebuild (and therefore re-pod and
-                # re-hash) from scratch; this is the only race-free reset —
-                # queued bodies still in flight may re-arm _prev_pods /
-                # _pod_digests after the fact, but a from-scratch build
-                # never consults them.
-                if self._graph_cache is not None:
-                    self._graph_cache.invalidate()
-                raise
-        else:
-            work()
-        self._head = time_id
-        return time_id
+                warnings.warn(msg, RuntimeWarning, stacklevel=3)
+        return graph, ginfo, n_leaf_copies
 
     def wait(self) -> None:
         self.saver.wait()
 
     def _save_body(self, time_id, graph, ginfo, accessed_vars,
-                   touched_prefixes, readonly_paths, parent, t_graph,
-                   n_leaf_copies=0, branch=None) -> None:
+                   touched_prefixes, readonly_paths, parent, stats,
+                   times=None, branch=None) -> None:
+        stats["t_submit_wait"] = times.submit_wait if times else 0.0
+        stats["t_queue"] = times.queue if times else 0.0
+        ph = Phases(stats, time_id)
         try:
-            self._save_body_inner(time_id, graph, ginfo, accessed_vars,
-                                  touched_prefixes, readonly_paths, parent,
-                                  t_graph, n_leaf_copies, branch)
+            with ph("body"):
+                self._save_body_inner(time_id, graph, ginfo, accessed_vars,
+                                      touched_prefixes, readonly_paths,
+                                      parent, ph, branch)
         except BaseException as exc:
             # A half-applied save poisons the reuse chain: the graph cache
             # has already advanced (build happens at save() call time), so
@@ -578,6 +599,8 @@ class Chipmink:
                     except Exception:
                         self._writer_lease = None
             raise
+        # the body's last act: the commit time is taken at this append
+        self.save_stats.append(stats)
 
     def _speculate(self, graph: ObjectGraph,
                    ginfo: Optional[IncrementalBuildInfo]) -> Optional[Set[str]]:
@@ -619,142 +642,145 @@ class Chipmink:
         return out or None
 
     def _save_body_inner(self, time_id, graph, ginfo, accessed_vars,
-                         touched_prefixes, readonly_paths, parent,
-                         t_graph, n_leaf_copies=0, branch=None) -> None:
-        stats: Dict[str, Any] = {"time_id": time_id, "t_graph": t_graph,
-                                 "n_leaf_copies": n_leaf_copies}
-        # Only the newest snapshot keeps its arrays: this body reads the
-        # previous graph by structure alone (AVF), and checkout, which
-        # drains saves first, reads the graph this body leaves behind.
-        # Dropping the older arrays frees that device state now.
-        if self._prev_graph is not None and self._prev_graph is not graph:
-            self._prev_graph = dataclasses.replace(self._prev_graph,
-                                                   arrays={})
-        if ginfo is not None:
-            stats["t_graph_inc"] = t_graph
-            stats["n_nodes_reused"] = ginfo.n_nodes_reused
-            stats["n_nodes_rebuilt"] = ginfo.n_nodes_rebuilt
-        t0 = _time.perf_counter()
-        if self.enable_avf:
-            active = self.avf.active_leaves(
-                graph,
-                readonly_paths=readonly_paths,
-                touched_prefixes=touched_prefixes,
-                prior_pods=self._prev_pods if accessed_vars is not None else None,
-                prior_graph=self._prev_graph,
-                accessed_vars=accessed_vars,
-            )
-        else:
-            active = {n.key for n in graph.leaf_nodes()}
-        stats["n_leaves"] = len(list(graph.leaf_nodes()))
-        stats["n_active_leaves"] = len(active)
-        stats["t_avf"] = _time.perf_counter() - t0
+                         touched_prefixes, readonly_paths, parent, ph,
+                         branch=None) -> None:
+        # Every statement below lies in exactly one top-level phase, so
+        # the phases' times add up to the body's (`core/phases.py`).
+        stats = ph.stats
+        with ph("avf"):
+            # Only the newest snapshot keeps its arrays: this body reads
+            # the previous graph by structure alone (AVF), and checkout,
+            # which drains saves first, reads the graph this body leaves
+            # behind.  Dropping the older arrays frees that device state.
+            if self._prev_graph is not None and self._prev_graph is not graph:
+                self._prev_graph = dataclasses.replace(self._prev_graph,
+                                                       arrays={})
+            if ginfo is not None:
+                stats["t_graph_inc"] = stats["t_graph"]
+                stats["n_nodes_reused"] = ginfo.n_nodes_reused
+                stats["n_nodes_rebuilt"] = ginfo.n_nodes_rebuilt
+            if self.enable_avf:
+                active = self.avf.active_leaves(
+                    graph,
+                    readonly_paths=readonly_paths,
+                    touched_prefixes=touched_prefixes,
+                    prior_pods=(self._prev_pods if accessed_vars is not None
+                                else None),
+                    prior_graph=self._prev_graph,
+                    accessed_vars=accessed_vars,
+                )
+            else:
+                active = {n.key for n in graph.leaf_nodes()}
+            stats["n_leaves"] = len(list(graph.leaf_nodes()))
+            stats["n_active_leaves"] = len(active)
 
-        t0 = _time.perf_counter()
-        spec = self._speculate(graph, ginfo)
-        report = self.detector.detect(graph, active, speculate=spec)
-        stats["n_chunks"] = len(report.digests)
-        stats["n_dirty_chunks"] = len(report.dirty)
-        stats["t_digest"] = _time.perf_counter() - t0
-        stats["n_digest_syncs"] = report.n_syncs
-        stats["n_spec_predicted"] = len(spec) if spec else 0
-        stats["n_spec_hits"] = report.n_spec_hits
-        stats["n_spec_misses"] = report.n_spec_misses
-        stats["n_fused_rows"] = report.fused_rows
+        with ph("digest"):
+            spec = self._speculate(graph, ginfo)
+            report = self.detector.detect(graph, active, speculate=spec,
+                                          phases=ph)
+            stats["n_chunks"] = len(report.digests)
+            stats["n_dirty_chunks"] = len(report.dirty)
+            stats["bytes_dirty"] = report.dirty_bytes
+            stats["n_digest_syncs"] = report.n_syncs
+            stats["n_spec_predicted"] = len(spec) if spec else 0
+            stats["n_spec_hits"] = report.n_spec_hits
+            stats["n_spec_misses"] = report.n_spec_misses
+            stats["n_fused_rows"] = report.fused_rows
 
-        if self.tracker is not None:
-            active_chunks = [n.key for n in graph.chunk_nodes()
-                             if "/".join(n.path) in active]
-            self.tracker.observe(graph, report.dirty, active_chunks)
+        with ph("observe"):
+            if self.tracker is not None:
+                active_chunks = [n.key for n in graph.chunk_nodes()
+                                 if "/".join(n.path) in active]
+                self.tracker.observe(graph, report.dirty, active_chunks)
 
         # podding: reuse the previous assignment verbatim when the graph
         # structure is unchanged (memo locals preserved, §7.3 stability);
         # otherwise rerun the full LGA walk — the parity oracle — with the
         # rebuilt-key set so feature preparation stays incremental.
-        t0 = _time.perf_counter()
-        pods_reused = (self.incremental and ginfo is not None
-                       and not ginfo.from_scratch
-                       and not ginfo.structural_change
-                       and self._prev_pods is not None)
-        if pods_reused:
-            asg = self._prev_pods
-            stats["n_pods_reused"] = len(asg.pods)
-        else:
-            asg = pod_graph(graph, self.policy,
-                            flip_ema=self.tracker.ema if self.tracker else None,
-                            memo_page_size=self.memo_page_size,
-                            changed_keys=(ginfo.rebuilt_keys
-                                          if ginfo is not None else None))
-            stats["n_pods_reused"] = 0
-        stats["n_pods"] = len(asg.pods)
-        stats["t_podding"] = _time.perf_counter() - t0
+        with ph("podding"):
+            pods_reused = (self.incremental and ginfo is not None
+                           and not ginfo.from_scratch
+                           and not ginfo.structural_change
+                           and self._prev_pods is not None)
+            if pods_reused:
+                asg = self._prev_pods
+                stats["n_pods_reused"] = len(asg.pods)
+            else:
+                asg = pod_graph(graph, self.policy,
+                                flip_ema=(self.tracker.ema if self.tracker
+                                          else None),
+                                memo_page_size=self.memo_page_size,
+                                changed_keys=(ginfo.rebuilt_keys
+                                              if ginfo is not None else None))
+                stats["n_pods_reused"] = 0
+            stats["n_pods"] = len(asg.pods)
 
         # decide phase: structural digests + synonym lookups; no payload
         # bytes move yet.  With a reused assignment, only pods touched by
         # dirty chunks or changed scalar values re-hash their digest; the
         # rest reuse the previous save's digest (bit-identical: the digest
         # is a pure function of unchanged inputs).
-        t0 = _time.perf_counter()
-        touched_pods = None
-        if pods_reused and self._pod_digests:
-            touched_pods = set()
-            for key in report.dirty:
-                nid = graph.by_key.get(key)
-                if nid is not None:
-                    touched_pods.add(asg.node_pod[nid])
-            for key in (ginfo.scalar_changed_keys if ginfo else ()):
-                nid = graph.by_key.get(key)
-                if nid is not None:
-                    touched_pods.add(asg.node_pod[nid])
-        pods_meta: Dict[int, Dict[str, Any]] = {}
-        written = aliased = digests_reused = 0
-        bytes_before = self.store.total_bytes()
-        #: the parent commit's digest per pod id — the delta base each
-        #: touched pod would chain to (captured before new_digests lands).
-        prev_pod_digests = self._pod_digests
-        new_digests: Dict[int, bytes] = {}
-        to_write: List[tuple] = []        # (pid, pod, dig_hex, digest)
-        aliased_entries: List[tuple] = []  # same shape; dedup-skipped pods
-        for pid, pod in asg.pods.items():
-            if touched_pods is not None and pid not in touched_pods \
-                    and pid in self._pod_digests:
-                digest = self._pod_digests[pid]
-                digests_reused += 1
-            else:
-                digest = pod_structural_digest(pod, graph, asg,
-                                               report.digests)
-            new_digests[pid] = digest
-            dig_hex = digest.hex()
-            skip = False
-            if self.enable_cd:
-                # only the thesaurus probe touches shared namespace state;
-                # hashing above runs lock-free so concurrent loads are not
-                # blocked for the duration of the decide phase.
-                with self.saver.l_ns:
-                    ref = self.thesaurus.lookup(digest)
-                if ref is not None:
-                    skip = True           # synonymous pod (§4.2)
-            if not skip:
-                if not self.enable_cd:
-                    # NoCD baseline: every save writes unconditionally
-                    # under a unique key (true snapshot cost, no dedup).
-                    h = hashlib.blake2b(digest, digest_size=16,
-                                        person=b"nocd")
-                    h.update(time_id.to_bytes(8, "little"))
-                    dig_hex = h.hexdigest()
-                to_write.append((pid, pod, dig_hex, digest))
-            else:
-                aliased += 1
-                aliased_entries.append((pid, pod, dig_hex, digest))
-            pods_meta[pid] = {
-                "d": dig_hex,
-                "pages": (asg.memo.pods[pid].pages
-                          if pid in asg.memo.pods else []),
-                "n": len(pod.node_ids),
-            }
-        self._pod_digests = new_digests
-        stats["n_pod_digests_reused"] = digests_reused
-        stats["t_decide"] = _time.perf_counter() - t0
+        with ph("decide"):
+            touched_pods = None
+            if pods_reused and self._pod_digests:
+                touched_pods = set()
+                for key in report.dirty:
+                    nid = graph.by_key.get(key)
+                    if nid is not None:
+                        touched_pods.add(asg.node_pod[nid])
+                for key in (ginfo.scalar_changed_keys if ginfo else ()):
+                    nid = graph.by_key.get(key)
+                    if nid is not None:
+                        touched_pods.add(asg.node_pod[nid])
+            pods_meta: Dict[int, Dict[str, Any]] = {}
+            written = aliased = digests_reused = 0
+            bytes_before = self.store.total_bytes()
+            #: the parent commit's digest per pod id — the delta base each
+            #: touched pod would chain to (captured before new_digests
+            #: lands).
+            prev_pod_digests = self._pod_digests
+            new_digests: Dict[int, bytes] = {}
+            to_write: List[tuple] = []        # (pid, pod, dig_hex, digest)
+            aliased_entries: List[tuple] = []  # same shape; dedup-skipped
+            for pid, pod in asg.pods.items():
+                if touched_pods is not None and pid not in touched_pods \
+                        and pid in self._pod_digests:
+                    digest = self._pod_digests[pid]
+                    digests_reused += 1
+                else:
+                    digest = pod_structural_digest(pod, graph, asg,
+                                                   report.digests)
+                new_digests[pid] = digest
+                dig_hex = digest.hex()
+                skip = False
+                if self.enable_cd:
+                    # only the thesaurus probe touches shared namespace
+                    # state; hashing above runs lock-free so concurrent
+                    # loads are not blocked for the decide phase.
+                    with self.saver.l_ns:
+                        ref = self.thesaurus.lookup(digest)
+                    if ref is not None:
+                        skip = True           # synonymous pod (§4.2)
+                if not skip:
+                    if not self.enable_cd:
+                        # NoCD baseline: every save writes unconditionally
+                        # under a unique key (true snapshot cost, no dedup).
+                        h = hashlib.blake2b(digest, digest_size=16,
+                                            person=b"nocd")
+                        h.update(time_id.to_bytes(8, "little"))
+                        dig_hex = h.hexdigest()
+                    to_write.append((pid, pod, dig_hex, digest))
+                else:
+                    aliased += 1
+                    aliased_entries.append((pid, pod, dig_hex, digest))
+                pods_meta[pid] = {
+                    "d": dig_hex,
+                    "pages": (asg.memo.pods[pid].pages
+                              if pid in asg.memo.pods else []),
+                    "n": len(pod.node_ids),
+                }
+            self._pod_digests = new_digests
+            stats["n_pod_digests_reused"] = digests_reused
 
         # intent phase (multi-writer): declare the commit — its TimeID
         # and every digest the manifest will reference — under the
@@ -764,25 +790,26 @@ class Chipmink:
         # then re-verified: a sweep that ran before the intent landed
         # may have deleted the blob the thesaurus still points at, in
         # which case the pod is rewritten instead of aliased.
-        lease = self._ensure_writer_lease()
-        n_alias_rewrites = 0
-        if lease is not None:
-            # the parent tid rides along in the intent so a concurrent
-            # sweep cannot reclaim the manifest this commit will chain
-            # to while the save is still in flight.
-            self.leases.set_intent(
-                lease,
-                time_ids=tuple(t for t in (time_id, parent)
-                               if t is not None),
-                digests=sorted({m["d"] for m in pods_meta.values()}))
-            for pid, pod, dig_hex, digest in aliased_entries:
-                if not self.store.has_pod(dig_hex):
-                    to_write.append((pid, pod, dig_hex, digest))
-                    with self.saver.l_ns:
-                        self.thesaurus.prune([dig_hex])
-                    aliased -= 1
-                    n_alias_rewrites += 1
-        stats["n_alias_rewrites"] = n_alias_rewrites
+        with ph("intent"):
+            lease = self._ensure_writer_lease()
+            n_alias_rewrites = 0
+            if lease is not None:
+                # the parent tid rides along in the intent so a concurrent
+                # sweep cannot reclaim the manifest this commit will chain
+                # to while the save is still in flight.
+                self.leases.set_intent(
+                    lease,
+                    time_ids=tuple(t for t in (time_id, parent)
+                                   if t is not None),
+                    digests=sorted({m["d"] for m in pods_meta.values()}))
+                for pid, pod, dig_hex, digest in aliased_entries:
+                    if not self.store.has_pod(dig_hex):
+                        to_write.append((pid, pod, dig_hex, digest))
+                        with self.saver.l_ns:
+                            self.thesaurus.prune([dig_hex])
+                        aliased -= 1
+                        n_alias_rewrites += 1
+            stats["n_alias_rewrites"] = n_alias_rewrites
 
         # gather phase.  Fused path: payload bytes of speculated chunks
         # already arrived with the digest fetch; only mispredicted chunks
@@ -790,18 +817,17 @@ class Chipmink:
         # every written pod — the single-sync save).  Non-fused: ONE
         # batched device fetch for every chunk of every dirty pod (clean
         # pods never touch the device either way).
-        t0 = _time.perf_counter()
-        gather_nodes = [graph.node(nid) for _, pod, _, _ in to_write
-                        for nid in pod.node_ids]
-        if self.fused:
-            chunk_bytes_of, gather_syncs = fused_chunk_fetch(
-                graph, gather_nodes, report.payload)
-            stats["n_corrective_syncs"] = gather_syncs
-        else:
-            chunk_bytes_of, gather_syncs = batched_chunk_fetch(
-                graph, gather_nodes)
-        stats["t_gather"] = _time.perf_counter() - t0
-        stats["n_gather_syncs"] = gather_syncs
+        with ph("gather"):
+            gather_nodes = [graph.node(nid) for _, pod, _, _ in to_write
+                            for nid in pod.node_ids]
+            if self.fused:
+                chunk_bytes_of, gather_syncs = fused_chunk_fetch(
+                    graph, gather_nodes, report.payload, phases=ph)
+                stats["n_corrective_syncs"] = gather_syncs
+            else:
+                chunk_bytes_of, gather_syncs = batched_chunk_fetch(
+                    graph, gather_nodes, phases=ph)
+            stats["n_gather_syncs"] = gather_syncs
 
         # write phase: serialize + store from the prefetched host bytes.
         # Thesaurus/store mutation is serialized under the namespace lock,
@@ -811,126 +837,150 @@ class Chipmink:
         # (retry_policy); puts are idempotent — a pod is content-addressed
         # and the rename is atomic — so a retried step never double-
         # applies.  InjectedCrash (BaseException) punches through.
-        t0 = _time.perf_counter()
-        n_retries = 0
-        # delta-chain eligibility for this save: only the reuse path has a
-        # per-pod parent digest AND the soundness proof (assignment reuse +
-        # detector mask) that non-patched entries are byte-identical.
-        delta_eligible = (self.delta_chains and self.enable_cd
-                          and pods_reused and touched_pods is not None)
-        scalar_changed = set(ginfo.scalar_changed_keys) if ginfo else set()
-        n_delta_pods = 0
-        chain_depth_max = 0
-        t_delta = 0.0
-        for pid, pod, dig_hex, digest in to_write:
-            data = serialize_pod(pod, graph, asg, chunk_bytes_of)
+        with ph("write"):
+            n_retries = 0
+            # delta-chain eligibility for this save: only the reuse path
+            # has a per-pod parent digest AND the soundness proof
+            # (assignment reuse + detector mask) that non-patched entries
+            # are byte-identical.
+            delta_eligible = (self.delta_chains and self.enable_cd
+                              and pods_reused and touched_pods is not None)
+            scalar_changed = (set(ginfo.scalar_changed_keys) if ginfo
+                              else set())
+            n_delta_pods = 0
+            chain_depth_max = 0
+            stats.update(t_serialize=0.0, t_delta_encode=0.0,
+                         t_store_put=0.0, t_ns_wait=0.0,
+                         bytes_serialized=0)
+            for pid, pod, dig_hex, digest in to_write:
+                with ph("write.serialize", "t_serialize"):
+                    data = serialize_pod(pod, graph, asg, chunk_bytes_of)
+                ph.add("bytes_serialized", len(data))
 
-            delta_blob = base_hex = None
-            delta_depth = 0
-            base = prev_pod_digests.get(pid) if delta_eligible else None
-            if base is not None and base != digest:
-                td0 = _time.perf_counter()
-                cand_hex = base.hex()
-                try:
-                    # depth the new pod would sit at if chained to base;
-                    # a missing/broken/cyclic base chain disqualifies.
-                    depth = self.store.pod_chain_depth(cand_hex) + 1
-                except (FileNotFoundError, ValueError):
-                    depth = None
-                if depth is not None and depth <= self.delta_policy.max_chain_depth:
-                    changed_locals = [
-                        i for i, nid in enumerate(pod.node_ids)
-                        if ((n := graph.node(nid)).kind == CHUNK
-                            and n.key in report.dirty)
-                        or n.key in scalar_changed]
-                    cand = encode_pod_delta(data, cand_hex, changed_locals)
-                    if self.delta_policy.admit(len(cand), len(data), depth):
-                        delta_blob, base_hex, delta_depth = cand, cand_hex, depth
-                t_delta += _time.perf_counter() - td0
+                delta_blob = base_hex = None
+                delta_depth = 0
+                base = prev_pod_digests.get(pid) if delta_eligible else None
+                if base is not None and base != digest:
+                    with ph("write.delta_encode", "t_delta_encode"):
+                        delta_blob, base_hex, delta_depth = \
+                            self._delta_candidate(pod, graph, report, data,
+                                                  base, scalar_changed)
 
-            def put_one(dig_hex=dig_hex, data=data, digest=digest,
-                        delta_blob=delta_blob) -> bool:
+                def put_one(dig_hex=dig_hex, data=data, digest=digest,
+                            delta_blob=delta_blob) -> bool:
+                    t0 = _time.perf_counter()
+                    with self.saver.l_ns:
+                        ph.add("t_ns_wait", _time.perf_counter() - t0)
+                        with ph("write.store_put", "t_store_put"):
+                            if not self.enable_cd:
+                                self.store.put_pod(dig_hex, data)
+                                return True
+                            if delta_blob is not None:
+                                fresh = self.store.put_pod_delta(dig_hex,
+                                                                 delta_blob)
+                            else:
+                                fresh = self.store.put_pod(dig_hex, data)
+                            self.thesaurus.insert(digest, dig_hex)
+                            return fresh
+
+                fresh, nr = call_with_retries(put_one, self.retry_policy)
+                n_retries += nr
+                if fresh:
+                    written += 1
+                    if delta_blob is not None:
+                        # the manifest records chain structure only for
+                        # pods this commit actually stored in delta form
+                        # (a dedup hit keeps whatever form the digest
+                        # already has).
+                        pods_meta[pid]["delta_of"] = base_hex
+                        n_delta_pods += 1
+                        chain_depth_max = max(chain_depth_max, delta_depth)
+                else:
+                    aliased += 1              # disk-level synonym
+            stats["n_retries"] = n_retries
+            stats["pods_written"] = written
+            stats["pods_aliased"] = aliased
+            stats["n_delta_pods"] = n_delta_pods
+            stats["chain_depth_max"] = chain_depth_max
+            stats["bytes_written"] = self.store.total_bytes() - bytes_before
+
+        # commit phase: manifest, refcount and refs compare-and-swap as
+        # one retried unit, then the intent is cleared.
+        with ph("commit"):
+            manifest = {
+                "time_id": time_id,
+                "parent": parent,
+                "root_pod": asg.root_pod,
+                "page_size": self.memo_page_size,
+                "pods": {str(pid): meta for pid, meta in pods_meta.items()},
+                # the save's full chunk-digest table, so a later delta-
+                # aware checkout can prime change detection without
+                # re-hashing
+                "chunks": pack_digest_table(report.digests),
+                "stats": {k: v for k, v in stats.items()
+                          if isinstance(v, (int, float, str))},
+            }
+
+            def commit() -> None:
                 with self.saver.l_ns:
-                    if self.enable_cd:
-                        if delta_blob is not None:
-                            fresh = self.store.put_pod_delta(dig_hex,
-                                                             delta_blob)
-                        else:
-                            fresh = self.store.put_pod(dig_hex, data)
-                        self.thesaurus.insert(digest, dig_hex)
-                        return fresh
-                    self.store.put_pod(dig_hex, data)
-                    return True
+                    # fencing gate: the refs CAS must not publish a commit
+                    # whose lease was reaped or taken over mid-save — a
+                    # fenced GC may already have swept what the dead
+                    # intent pinned.  LeaseLost aborts the save (not
+                    # retried: it is a RuntimeError, outside the
+                    # transient-OSError class).
+                    if lease is not None:
+                        self.leases.check(lease)
+                    # the manifest put is the data commit point; the refs
+                    # CAS in record() is the visibility commit point.
+                    # Both are idempotent (atomic rename; CAS rebases;
+                    # record_commit is a no-op for an already-counted
+                    # TimeID), so the triple is safe to retry as a unit on
+                    # transient I/O errors.  The refcount lands BEFORE the
+                    # refs CAS: a crash in between leaves a counted
+                    # dangling commit — conservative (pods kept, never
+                    # lost) and exactly what rebuild() computes.
+                    self.store.put_manifest(time_id, manifest)
+                    if self.refcounts is not None:
+                        self.refcounts.record_commit(time_id, manifest)
+                    self.versions.record(time_id, parent, branch=branch)
 
-            fresh, nr = call_with_retries(put_one, self.retry_policy)
-            n_retries += nr
-            if fresh:
-                written += 1
-                if delta_blob is not None:
-                    # the manifest records chain structure only for pods
-                    # this commit actually stored in delta form (a dedup
-                    # hit keeps whatever form the digest already has).
-                    pods_meta[pid]["delta_of"] = base_hex
-                    n_delta_pods += 1
-                    chain_depth_max = max(chain_depth_max, delta_depth)
-            else:
-                aliased += 1              # disk-level synonym
-        stats["t_write"] = _time.perf_counter() - t0
-        stats["n_retries"] = n_retries
-        stats["pods_written"] = written
-        stats["pods_aliased"] = aliased
-        stats["n_delta_pods"] = n_delta_pods
-        stats["t_delta_encode"] = t_delta
-        stats["chain_depth_max"] = chain_depth_max
-        stats["bytes_written"] = self.store.total_bytes() - bytes_before
+            _, nr = call_with_retries(commit, self.retry_policy)
+            stats["n_retries"] = n_retries + nr
+            if lease is not None:
+                # the commit is now pinned by refs; the intent has done
+                # its job.  Best-effort: a lease lost in this instant
+                # cannot un-commit anything.
+                try:
+                    self.leases.clear_intent(lease)
+                except Exception:
+                    pass
+            self._prev_pods = asg
+            self._prev_graph = graph
 
-        manifest = {
-            "time_id": time_id,
-            "parent": parent,
-            "root_pod": asg.root_pod,
-            "page_size": self.memo_page_size,
-            "pods": {str(pid): meta for pid, meta in pods_meta.items()},
-            # the save's full chunk-digest table, so a later delta-aware
-            # checkout can prime change detection without re-hashing
-            "chunks": pack_digest_table(report.digests),
-            "stats": {k: v for k, v in stats.items()
-                      if isinstance(v, (int, float, str))},
-        }
-        def commit() -> None:
-            with self.saver.l_ns:
-                # fencing gate: the refs CAS must not publish a commit
-                # whose lease was reaped or taken over mid-save — a
-                # fenced GC may already have swept what the dead intent
-                # pinned.  LeaseLost aborts the save (not retried: it is
-                # a RuntimeError, outside the transient-OSError class).
-                if lease is not None:
-                    self.leases.check(lease)
-                # the manifest put is the data commit point; the refs CAS
-                # in record() is the visibility commit point.  Both are
-                # idempotent (atomic rename; CAS rebases; record_commit is
-                # a no-op for an already-counted TimeID), so the triple is
-                # safe to retry as a unit on transient I/O errors.  The
-                # refcount lands BEFORE the refs CAS: a crash in between
-                # leaves a counted dangling commit — conservative (pods
-                # kept, never lost) and exactly what rebuild() computes.
-                self.store.put_manifest(time_id, manifest)
-                if self.refcounts is not None:
-                    self.refcounts.record_commit(time_id, manifest)
-                self.versions.record(time_id, parent, branch=branch)
-
-        _, nr = call_with_retries(commit, self.retry_policy)
-        stats["n_retries"] = n_retries + nr
-        if lease is not None:
-            # the commit is now pinned by refs; the intent has done its
-            # job.  Best-effort: a lease lost in this instant cannot
-            # un-commit anything.
-            try:
-                self.leases.clear_intent(lease)
-            except Exception:
-                pass
-        self._prev_pods = asg
-        self._prev_graph = graph
-        self.save_stats.append(stats)
+    def _delta_candidate(self, pod, graph, report, data, base,
+                         scalar_changed) -> tuple:
+        """(delta blob, base hex, chain depth) to store `pod` as a delta
+        against its parent-commit digest `base`, or (None, None, 0) where
+        the cost model or the base's chain refuses it."""
+        cand_hex = base.hex()
+        try:
+            # depth the new pod would sit at if chained to base; a
+            # missing/broken/cyclic base chain disqualifies.
+            depth = self.store.pod_chain_depth(cand_hex) + 1
+        except (FileNotFoundError, ValueError):
+            return None, None, 0
+        if depth > self.delta_policy.max_chain_depth:
+            return None, None, 0
+        changed_locals = [
+            i for i, nid in enumerate(pod.node_ids)
+            if ((n := graph.node(nid)).kind == CHUNK
+                and n.key in report.dirty)
+            or n.key in scalar_changed]
+        cand = encode_pod_delta(data, cand_hex, changed_locals)
+        if self.delta_policy.admit(len(cand), len(data), depth):
+            return cand, cand_hex, depth
+        return None, None, 0
 
     # ------------------------------------------------------------------
     # load

@@ -28,6 +28,7 @@ from .graph import (ALIAS, CHUNK, CONTAINER, LEAF, SCALAR, Node, ObjectGraph,
                     chunk_slice, fetch_whole_leaf, path_str)
 from .lga import BUNDLE, SPLIT_CONTINUE, SPLIT_FINAL, PodState, PoddingPolicy
 from .memo import CROSS_POD_OFFSET, GlobalMemoSpace
+from .phases import NO_PHASES
 
 
 @dataclasses.dataclass
@@ -201,7 +202,8 @@ def default_chunk_bytes(graph: ObjectGraph) -> Callable[[Node], bytes]:
     return get
 
 
-def batched_chunk_fetch(graph: ObjectGraph, nodes: Sequence[Node]
+def batched_chunk_fetch(graph: ObjectGraph, nodes: Sequence[Node],
+                        phases=NO_PHASES
                         ) -> Tuple[Callable[[Node], bytes], int]:
     """Gather payload bytes of every CHUNK node in `nodes` at once.
 
@@ -211,7 +213,8 @@ def batched_chunk_fetch(graph: ObjectGraph, nodes: Sequence[Node]
     serializer used to pay.  Each device leaf crosses whole or as its
     requested chunks, as `fetch_whole_leaf` decides, so a dense save
     holds no second copy of the state on the device.  Returns (lookup fn
-    for serialize_pod, number of device syncs issued: 0 or 1).
+    for serialize_pod, number of device syncs issued: 0 or 1).  The
+    bytes fetched are counted into `phases` (`core.phases.Phases`).
     """
     import jax
 
@@ -241,6 +244,7 @@ def batched_chunk_fetch(graph: ObjectGraph, nodes: Sequence[Node]
     if parts:
         fetched = jax.device_get([p for p, _, _ in parts])
         n_syncs = 1
+        phases.add("bytes_fetched", sum(int(f.nbytes) for f in fetched))
         # release each host array as it is converted so peak memory stays
         # ~1x the fetched payload, not 2x
         for i, (_, chunks, whole) in enumerate(parts):
@@ -257,7 +261,7 @@ def batched_chunk_fetch(graph: ObjectGraph, nodes: Sequence[Node]
 
 
 def fused_chunk_fetch(graph: ObjectGraph, nodes: Sequence[Node],
-                      payload: Dict[str, bytes]
+                      payload: Dict[str, bytes], phases=NO_PHASES
                       ) -> Tuple[Callable[[Node], bytes], int]:
     """Payload-first gather for the fused single-sync save.
 
@@ -269,7 +273,7 @@ def fused_chunk_fetch(graph: ObjectGraph, nodes: Sequence[Node],
     0 when speculation covered every device chunk, else 1).
     """
     missing = [n for n in nodes if n.kind == CHUNK and n.key not in payload]
-    corrective, n_syncs = batched_chunk_fetch(graph, missing)
+    corrective, n_syncs = batched_chunk_fetch(graph, missing, phases)
 
     def get(node: Node) -> bytes:
         b = payload.get(node.key)

@@ -57,7 +57,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -309,8 +309,84 @@ def seed_device_table(plan: BatchPlan,
     return DeviceTable(plan=plan, digs=digs, valid=valid)
 
 
-def _digest_device(plan: BatchPlan, arrays: Sequence[Any], *, seed: int,
-                   use_kernel: bool) -> List[np.ndarray]:
+@dataclasses.dataclass
+class PendingDigest:
+    """A digest whose device work is dispatched and not yet fetched.
+
+    `fetch` brings every device output to the host in ONE
+    `jax.device_get` (none when there are no device leaves) and returns
+    the bytes it brought; `finish` unpacks them into the result.  The
+    split lets a caller time the launch, the fetch and the unpack apart.
+    """
+    handles: Any                       # device outputs, None when none
+    unpack: Callable[[Any], Any]       # fetched outputs -> result
+    fetched: Any = None
+
+    def fetch(self) -> int:
+        if self.handles is None:
+            return 0
+        self.fetched = jax.device_get(self.handles)   # the ONE sync
+        self.handles = None
+        return sum(int(x.nbytes)
+                   for x in jax.tree_util.tree_leaves(self.fetched))
+
+    def finish(self) -> Any:
+        fetched, self.fetched = self.fetched, None
+        return self.unpack(fetched)
+
+    def result(self) -> Any:
+        """Fetch and finish."""
+        self.fetch()
+        return self.finish()
+
+
+def _plan_groups(items: Sequence[Tuple[str, Any]], chunk_bytes: int):
+    """(device group, host group) of the (leaf key, array) pairs, each
+    as (plan, arrays), or None when the group is empty."""
+    dev: List[Tuple[str, Any]] = []
+    host: List[Tuple[str, Any]] = []
+    for key, arr in items:
+        (host if isinstance(arr, np.ndarray) else dev).append((key, arr))
+    out = []
+    for group in (dev, host):
+        if not group:
+            out.append(None)
+            continue
+        specs = tuple(
+            (k, tuple(int(d) for d in a.shape), str(np.dtype(a.dtype)))
+            for k, a in group)
+        out.append((plan_leaves(specs, chunk_bytes), [a for _, a in group]))
+    return out
+
+
+def _assemble(groups: Sequence[Tuple[BatchPlan, List[np.ndarray]]]):
+    """(plan, per-bucket digest mats) groups -> (chunk keys, (C, 4) digest
+    matrix, leaf key -> first row), in slot order."""
+    keys: List[str] = []
+    mats: List[np.ndarray] = []
+    leaf_rows: Dict[str, int] = {}
+    offset = 0
+    for plan, digs in groups:
+        plan_keys, plan_offsets = _plan_slots(plan)
+        keys.extend(plan_keys)
+        mats.extend(digs)
+        for lkey, row in plan_offsets:
+            leaf_rows[lkey] = offset + row
+        offset += plan.n_chunks
+    mat = (np.concatenate(mats, axis=0) if mats
+           else np.zeros((0, 4), np.uint32))
+    return keys, mat, leaf_rows
+
+
+def _bucket_rows(plan: BatchPlan, fetched: Sequence[Any],
+                 dtype) -> List[np.ndarray]:
+    """Per-bucket fetched outputs without their padding rows."""
+    return [np.asarray(h, dtype)[:b.n_rows]
+            for b, h in zip(plan.buckets, fetched)]
+
+
+def _dispatch_device(plan: BatchPlan, arrays: Sequence[Any], *, seed: int,
+                     use_kernel: bool) -> List[Any]:
     packed = _packer_for(plan)(*arrays)
     lengths = _plan_lengths(plan)
     digs = []
@@ -321,9 +397,7 @@ def _digest_device(plan: BatchPlan, arrays: Sequence[Any], *, seed: int,
         else:
             d = fingerprint_words_ref(words, jnp.asarray(lens), seed=seed)
         digs.append(d)
-    host = jax.device_get(digs)        # the ONE sync of the digest phase
-    return [np.asarray(h, np.uint32)[:b.n_rows]
-            for b, h in zip(plan.buckets, host)]
+    return digs
 
 
 def _digest_host(plan: BatchPlan, arrays: Sequence[np.ndarray], *,
@@ -342,56 +416,46 @@ def _digest_host(plan: BatchPlan, arrays: Sequence[np.ndarray], *,
     return out
 
 
-def digest_leaves(items: Sequence[Tuple[str, Any]], *, chunk_bytes: int,
-                  seed: int = 0, use_kernel: bool = True) -> DigestResult:
-    """Digest every chunk of the given (leaf key, array) pairs.
+def dispatch_leaves(items: Sequence[Tuple[str, Any]], *, chunk_bytes: int,
+                    seed: int = 0, use_kernel: bool = True) -> PendingDigest:
+    """Launch the digest of every chunk of the given (leaf key, array)
+    pairs; the pending digest finishes as a `DigestResult`.
 
     Device (jax) leaves go through the bucketed Pallas path and cost one
-    `jax.device_get` total; host (numpy) leaves go through the bucketed
-    numpy twin and cost zero.  Result rows are bucket-major with all
-    device buckets first.
+    `jax.device_get` total, at `PendingDigest.fetch`; host (numpy) leaves
+    go through the bucketed numpy twin here and cost zero.  Result rows
+    are bucket-major with all device buckets first.
     """
-    dev: List[Tuple[str, Any]] = []
-    host: List[Tuple[str, Any]] = []
-    for key, arr in items:
-        (host if isinstance(arr, np.ndarray) else dev).append((key, arr))
+    dev, host = _plan_groups(items, chunk_bytes)
+    handles = None
+    if dev is not None:
+        handles = _dispatch_device(*dev, seed=seed, use_kernel=use_kernel)
+    host_digs = _digest_host(*host, seed=seed) if host is not None else None
 
-    keys: List[str] = []
-    mats: List[np.ndarray] = []
-    leaf_rows: Dict[str, int] = {}
-    n_syncs = 0
-    offset = 0
-    for group, is_dev in ((dev, True), (host, False)):
-        if not group:
-            continue
-        specs = tuple(
-            (k, tuple(int(d) for d in a.shape), str(np.dtype(a.dtype)))
-            for k, a in group)
-        plan = plan_leaves(specs, chunk_bytes)
-        arrays = [a for _, a in group]
-        if is_dev:
-            bucket_digs = _digest_device(plan, arrays, seed=seed,
-                                         use_kernel=use_kernel)
-            n_syncs += 1
-        else:
-            bucket_digs = _digest_host(plan, arrays, seed=seed)
-        plan_keys, plan_offsets = _plan_slots(plan)
-        keys.extend(plan_keys)
-        mats.extend(bucket_digs)
-        for lkey, row in plan_offsets:
-            leaf_rows[lkey] = offset + row
-        offset += plan.n_chunks
+    def unpack(fetched) -> DigestResult:
+        groups = []
+        if dev is not None:
+            groups.append((dev[0], _bucket_rows(dev[0], fetched, np.uint32)))
+        if host is not None:
+            groups.append((host[0], host_digs))
+        keys, mat, leaf_rows = _assemble(groups)
+        return DigestResult(keys=keys, mat=mat, n_syncs=int(dev is not None),
+                            leaf_rows=leaf_rows)
 
-    mat = (np.concatenate(mats, axis=0) if mats
-           else np.zeros((0, 4), np.uint32))
-    return DigestResult(keys=keys, mat=mat, n_syncs=n_syncs,
-                        leaf_rows=leaf_rows)
+    return PendingDigest(handles, unpack)
 
 
-def _digest_device_fused(plan: BatchPlan, arrays: Sequence[Any], *,
-                         seed: int, use_kernel: bool,
-                         table: DeviceTable,
-                         spec_rows: Dict[int, np.ndarray]):
+def digest_leaves(items: Sequence[Tuple[str, Any]], *, chunk_bytes: int,
+                  seed: int = 0, use_kernel: bool = True) -> DigestResult:
+    """`dispatch_leaves`, fetched and finished."""
+    return dispatch_leaves(items, chunk_bytes=chunk_bytes, seed=seed,
+                           use_kernel=use_kernel).result()
+
+
+def _dispatch_device_fused(plan: BatchPlan, arrays: Sequence[Any], *,
+                           seed: int, use_kernel: bool,
+                           table: DeviceTable,
+                           spec_rows: Dict[int, np.ndarray]):
     """Fused per-bucket digest+compare plus the speculated payload.
 
     `spec_rows` maps a bucket index to the sorted bucket rows whose
@@ -403,8 +467,9 @@ def _digest_device_fused(plan: BatchPlan, arrays: Sequence[Any], *,
     (repeating the first row) so the gather's jit cache stops recompiling
     as the speculation set fluctuates.
 
-    Returns (digest mats, dirty masks, {chunk key: payload bytes}, new
-    DeviceTable) after exactly ONE `jax.device_get`.
+    Returns (device outputs for the ONE `jax.device_get`, unpack: fetched
+    outputs -> (digest mats, dirty masks, {chunk key: payload bytes}, new
+    DeviceTable)).
     """
     keys, _ = _plan_slots(plan)
     lengths = _plan_lengths(plan)
@@ -412,9 +477,12 @@ def _digest_device_fused(plan: BatchPlan, arrays: Sequence[Any], *,
     packed = list(_packer_for(plan)(*arrays))
     digs_dev: List[Any] = []
     masks_dev: List[Any] = []
-    gathers: List[Tuple[int, np.ndarray, Any]] = []
-    # (bucket index, slot, device leaf, speculated chunk indices)
-    leaves: List[Tuple[int, LeafSlot, Any, np.ndarray]] = []
+    # (bucket index, gathered rows) and the gathered device buffers
+    gathers: List[Tuple[int, np.ndarray]] = []
+    gathered: List[Any] = []
+    # (bucket index, slot, speculated chunk indices) and the device leaves
+    whole: List[Tuple[int, LeafSlot, np.ndarray]] = []
+    whole_dev: List[Any] = []
     for bi, b in enumerate(plan.buckets):
         # only the kernel and the gather below read the packed bucket:
         # holding no reference past them frees it as soon as they finish,
@@ -442,38 +510,117 @@ def _digest_device_fused(plan: BatchPlan, arrays: Sequence[Any], *,
             if hi == lo:
                 continue
             if fetch_whole_leaf(int(hi - lo), slot.n_chunks):
-                leaves.append((bi, slot, arr, rows[lo:hi] - slot.row0))
+                whole.append((bi, slot, rows[lo:hi] - slot.row0))
+                whole_dev.append(arr)
             else:
                 sparse.append(rows[lo:hi])
         if sparse:
             rows = np.concatenate(sparse)
             pad = np.full(pow2ceil(len(rows)) - len(rows), rows[0])
             idx = jnp.asarray(np.concatenate([rows, pad]), jnp.int32)
-            gathers.append((bi, rows, words[idx]))
+            gathers.append((bi, rows))
+            gathered.append(words[idx])
     del words
-    host = jax.device_get([digs_dev, masks_dev, [g for *_, g in gathers],
-                           [a for _, _, a, _ in leaves]])  # the ONE sync
-    payload: Dict[str, bytes] = {}
-    for (bi, rows, _), fetched in zip(gathers, host[2]):
-        for i, r in enumerate(rows):
-            payload[keys[base[bi] + r]] = \
-                fetched[i].tobytes()[:int(lengths[bi][r])]
-    for (bi, slot, _, chunk_ids), leaf in zip(leaves, host[3]):
-        flat = np.asarray(leaf).reshape(-1)
-        for ci in chunk_ids:
-            lo = ci * slot.elems
-            payload[keys[base[bi] + slot.row0 + ci]] = \
-                flat[lo:lo + slot.elems].tobytes()
-    dig_mats = [np.asarray(h, np.uint32)[:b.n_rows]
-                for b, h in zip(plan.buckets, host[0])]
-    masks = [np.asarray(h, np.uint8)[:b.n_rows]
-             for b, h in zip(plan.buckets, host[1])]
     # padded digest rows stay on device as the next save's prev table:
-    # every digested row is now trusted.
+    # every digested row is trusted once fetched.
     new_table = DeviceTable(
         plan=plan, digs=digs_dev,
         valid=[np.ones((b.n_rows,), bool) for b in plan.buckets])
-    return dig_mats, masks, payload, new_table
+
+    def unpack(host):
+        digs, masks, gathered_host, whole_host = host
+        payload: Dict[str, bytes] = {}
+        for (bi, rows), fetched in zip(gathers, gathered_host):
+            for i, r in enumerate(rows):
+                payload[keys[base[bi] + r]] = \
+                    fetched[i].tobytes()[:int(lengths[bi][r])]
+        for (bi, slot, chunk_ids), leaf in zip(whole, whole_host):
+            flat = np.asarray(leaf).reshape(-1)
+            for ci in chunk_ids:
+                lo = ci * slot.elems
+                payload[keys[base[bi] + slot.row0 + ci]] = \
+                    flat[lo:lo + slot.elems].tobytes()
+        return (_bucket_rows(plan, digs, np.uint32),
+                _bucket_rows(plan, masks, np.uint8), payload, new_table)
+
+    return [digs_dev, masks_dev, gathered, whole_dev], unpack
+
+
+def _spec_rows(plan: BatchPlan, spec_keys: set) -> Dict[int, np.ndarray]:
+    """Speculated chunk keys -> sorted local rows per bucket index."""
+    spec_rows: Dict[int, List[int]] = {}
+    if spec_keys:
+        row_of = {k: r for r, k in enumerate(_plan_slots(plan)[0])}
+        base = np.cumsum([0] + [b.n_rows for b in plan.buckets])
+        for k in spec_keys:
+            r = row_of.get(k)
+            if r is not None:
+                bi = int(np.searchsorted(base, r, side="right")) - 1
+                spec_rows.setdefault(bi, []).append(r - int(base[bi]))
+    return {bi: np.asarray(sorted(r), np.int64)
+            for bi, r in spec_rows.items()}
+
+
+def dispatch_leaves_fused(items: Sequence[Tuple[str, Any]], *,
+                          chunk_bytes: int, seed: int = 0,
+                          use_kernel: bool = True,
+                          table: Optional[DeviceTable] = None,
+                          lookup=None,
+                          spec_keys: Optional[set] = None) -> PendingDigest:
+    """Launch the fused single-sync digest of the given (leaf key, array)
+    pairs.
+
+    Device leaves run the fused digest+compare kernel against the
+    device-resident previous table (`table` when its plan matches this
+    call's leaf specs, else re-seeded from `lookup`), with the packed
+    rows of `spec_keys` chunks compacted into the same fetch — ONE
+    blocking `jax.device_get` total, at `PendingDigest.fetch`.  Host
+    leaves take the numpy twin here (dirty = -1: the caller's host
+    compare decides).
+
+    The pending digest finishes as (result, new device table to carry to
+    the next save).
+    """
+    dev, host = _plan_groups(items, chunk_bytes)
+    handles = unpack_dev = None
+    if dev is not None:
+        plan, arrays = dev
+        if table is None or table.plan is not plan:
+            # plan changed (or table imported/never built): re-seed from
+            # the host table; rows it has never seen compare against
+            # zeros and are forced dirty below.
+            table = seed_device_table(
+                plan, lookup if lookup is not None else lambda k: None)
+        handles, unpack_dev = _dispatch_device_fused(
+            plan, arrays, seed=seed, use_kernel=use_kernel, table=table,
+            spec_rows=_spec_rows(plan, spec_keys or set()))
+    host_digs = _digest_host(*host, seed=seed) if host is not None else None
+
+    def unpack(fetched) -> Tuple[FusedDigestResult, Optional[DeviceTable]]:
+        groups = []
+        dirty_parts: List[np.ndarray] = []
+        payload: Dict[str, bytes] = {}
+        new_table = table              # preserved when no device leaves
+        if dev is not None:
+            dig_mats, masks, payload, new_table = unpack_dev(fetched)
+            for bi, m in enumerate(masks):
+                d = m.astype(np.int8)
+                d[~table.valid[bi]] = 1      # no trusted prev: dirty
+                dirty_parts.append(d)
+            groups.append((dev[0], dig_mats))
+        if host is not None:
+            groups.append((host[0], host_digs))
+            dirty_parts.append(np.full((host[0].n_chunks,), -1, np.int8))
+        keys, mat, leaf_rows = _assemble(groups)
+        dirty = (np.concatenate(dirty_parts) if dirty_parts
+                 else np.zeros((0,), np.int8))
+        res = FusedDigestResult(keys=keys, mat=mat,
+                                n_syncs=int(dev is not None),
+                                leaf_rows=leaf_rows, dirty=dirty,
+                                payload=payload)
+        return res, new_table
+
+    return PendingDigest(handles, unpack)
 
 
 def digest_leaves_fused(items: Sequence[Tuple[str, Any]], *,
@@ -484,84 +631,11 @@ def digest_leaves_fused(items: Sequence[Tuple[str, Any]], *,
                         spec_keys: Optional[set] = None
                         ) -> Tuple[FusedDigestResult,
                                    Optional[DeviceTable]]:
-    """Fused single-sync digest of the given (leaf key, array) pairs.
-
-    Device leaves run the fused digest+compare kernel against the
-    device-resident previous table (`table` when its plan matches this
-    call's leaf specs, else re-seeded from `lookup`), with the packed
-    rows of `spec_keys` chunks compacted into the same fetch — ONE
-    blocking `jax.device_get` total.  Host leaves take the numpy twin
-    (dirty = -1: the caller's host compare decides).
-
-    Returns (result, new device table to carry to the next save).
-    """
-    dev: List[Tuple[str, Any]] = []
-    host: List[Tuple[str, Any]] = []
-    for key, arr in items:
-        (host if isinstance(arr, np.ndarray) else dev).append((key, arr))
-
-    keys: List[str] = []
-    mats: List[np.ndarray] = []
-    dirty_parts: List[np.ndarray] = []
-    leaf_rows: Dict[str, int] = {}
-    payload: Dict[str, bytes] = {}
-    new_table = table                  # preserved when no device leaves
-    n_syncs = 0
-    offset = 0
-    spec_keys = spec_keys or set()
-    for group, is_dev in ((dev, True), (host, False)):
-        if not group:
-            continue
-        specs = tuple(
-            (k, tuple(int(d) for d in a.shape), str(np.dtype(a.dtype)))
-            for k, a in group)
-        plan = plan_leaves(specs, chunk_bytes)
-        arrays = [a for _, a in group]
-        plan_keys, plan_offsets = _plan_slots(plan)
-        if is_dev:
-            if table is None or table.plan is not plan:
-                # plan changed (or table imported/never built): re-seed
-                # from the host table; rows it has never seen compare
-                # against zeros and are forced dirty below.
-                table = seed_device_table(
-                    plan, lookup if lookup is not None else lambda k: None)
-            # speculated chunk keys -> sorted local rows per bucket
-            spec_rows: Dict[int, List[int]] = {}
-            if spec_keys:
-                row_of = {k: r for r, k in enumerate(plan_keys)}
-                base = np.cumsum([0] + [b.n_rows for b in plan.buckets])
-                for k in spec_keys:
-                    r = row_of.get(k)
-                    if r is not None:
-                        bi = int(np.searchsorted(base, r, side="right")) - 1
-                        spec_rows.setdefault(bi, []).append(r - int(base[bi]))
-            dig_mats, masks, spec_payload, new_table = _digest_device_fused(
-                plan, arrays, seed=seed, use_kernel=use_kernel, table=table,
-                spec_rows={bi: np.asarray(sorted(r), np.int64)
-                           for bi, r in spec_rows.items()})
-            payload.update(spec_payload)
-            n_syncs += 1
-            for bi, (b, m) in enumerate(zip(plan.buckets, masks)):
-                d = m.astype(np.int8)
-                d[~table.valid[bi]] = 1      # no trusted prev: dirty
-                dirty_parts.append(d)
-            mats.extend(dig_mats)
-        else:
-            mats.extend(_digest_host(plan, arrays, seed=seed))
-            dirty_parts.append(np.full((plan.n_chunks,), -1, np.int8))
-        keys.extend(plan_keys)
-        for lkey, row in plan_offsets:
-            leaf_rows[lkey] = offset + row
-        offset += plan.n_chunks
-
-    mat = (np.concatenate(mats, axis=0) if mats
-           else np.zeros((0, 4), np.uint32))
-    dirty = (np.concatenate(dirty_parts) if dirty_parts
-             else np.zeros((0,), np.int8))
-    res = FusedDigestResult(keys=keys, mat=mat, n_syncs=n_syncs,
-                            leaf_rows=leaf_rows, dirty=dirty,
-                            payload=payload)
-    return res, new_table
+    """`dispatch_leaves_fused`, fetched and finished: (result, new device
+    table to carry to the next save)."""
+    return dispatch_leaves_fused(
+        items, chunk_bytes=chunk_bytes, seed=seed, use_kernel=use_kernel,
+        table=table, lookup=lookup, spec_keys=spec_keys).result()
 
 
 def tree_fingerprint_batched(graph: ObjectGraph, *, active_leaf_paths=None,
