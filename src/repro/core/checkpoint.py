@@ -852,6 +852,7 @@ class Chipmink:
             stats.update(t_serialize=0.0, t_delta_encode=0.0,
                          t_store_put=0.0, t_ns_wait=0.0,
                          bytes_serialized=0)
+            fs_calls_before = self.store.stats.pod_fs_calls
             for pid, pod, dig_hex, digest in to_write:
                 with ph("write.serialize", "t_serialize"):
                     data = serialize_pod(pod, graph, asg, chunk_bytes_of)
@@ -903,6 +904,8 @@ class Chipmink:
             stats["n_delta_pods"] = n_delta_pods
             stats["chain_depth_max"] = chain_depth_max
             stats["bytes_written"] = self.store.total_bytes() - bytes_before
+            stats["n_store_fs_calls"] = (self.store.stats.pod_fs_calls
+                                         - fs_calls_before)
 
         # commit phase: manifest, refcount and refs compare-and-swap as
         # one retried unit, then the intent is cleared.

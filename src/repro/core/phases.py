@@ -27,7 +27,9 @@ Phases of one save (``save_stats`` key in brackets):
 
 Counters: ``bytes_fetched`` (every byte brought from device to host),
 ``bytes_dirty`` (true lengths of the dirty chunks), ``bytes_serialized``
-(pod bytes serialized).
+(pod bytes serialized), ``n_store_fs_calls`` (the store's
+``pod_fs_calls`` across the write phase: path-resolving filesystem
+calls of its pod probes and writes).
 """
 from __future__ import annotations
 

@@ -26,7 +26,14 @@ version/fsck.py for the full commit protocol: pods → manifest → refs).
 ``FileStore(fsync=True)`` upgrades atomicity to durability: file contents
 and the containing directory entry are fsynced before the rename is
 considered landed (slower; for stores that must survive power loss, not
-just process death).
+just process death).  Pod writes resolve their names against one held
+descriptor of the store's ``pods/`` directory (``<shard>/<digest>.pod``,
+two components, with each shard directory made once per instance);
+the on-disk layout is the same as with full paths.  A ``pods/`` removed
+and remade underneath a live store is noticed, not written past: Linux
+creates nothing in a removed directory, so the create fails, and a
+probe that misses finds the held directory unlinked; either remakes and
+holds the new directory and runs the call once more.
 
 Delta-chain pod storage
 -----------------------
@@ -69,8 +76,9 @@ from __future__ import annotations
 import os
 import threading
 import time
+import weakref
 import zlib
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Dict, Iterable, List, Optional, Set, Tuple
 
 import msgpack
 
@@ -86,6 +94,18 @@ except Exception:  # pragma: no cover
 #: fallback — compress=True must always compress).
 _CODEC_ZSTD = b"\x01"
 _CODEC_ZLIB = b"\x02"
+
+#: pod files are named relative to a held descriptor of ``pods/`` where
+#: the platform resolves names against one (`os.replace` shares
+#: `os.rename`'s entry); elsewhere every call takes the full path.
+_RELATIVE_IO = {os.open, os.stat, os.rename, os.mkdir,
+                os.unlink} <= os.supports_dir_fd
+_DIR_FLAGS = os.O_RDONLY | getattr(os, "O_DIRECTORY", 0)
+
+
+def _close_all(fds: List[int]) -> None:
+    for fd in fds:
+        os.close(fd)
 
 
 class StoreStats:
@@ -112,6 +132,10 @@ class StoreStats:
         self.delta_bytes_written = 0  # stored bytes of those deltas
         self.chain_reads = 0          # get_pod calls that walked a chain
         self.pods_rematerialized = 0  # delta pods rewritten whole (GC/fsck)
+        # path-resolving filesystem calls of pod probes and pod writes
+        # (file backend): each stat, create, rename, shard mkdir and,
+        # with fsync, directory open; not write or close
+        self.pod_fs_calls = 0
 
     def as_dict(self) -> Dict[str, Any]:
         return dict(self.__dict__)
@@ -596,6 +620,17 @@ class FileStore(BaseStore):
     peer's lock is honored up to ``LOCK_TIMEOUT_S``.  `sweep_tmp` (and
     therefore fsck) applies the same staleness test, so it can run
     while writers are active without breaking their critical sections.
+
+    Pod files are named relative to one descriptor of ``pods/``, held
+    from construction until the store is dropped: a new pod costs two
+    probes, a create and a rename of two-component names, plus one
+    ``mkdir`` the first time this instance writes to its shard.  Where
+    ``pods/`` is removed and remade underneath, the failing call holds
+    the new directory and runs once more (module docstring); a
+    ``pods/`` *renamed* away keeps this instance's pods, as any open
+    directory would.  Dedup still reads the disk on every put.  Where
+    the platform names nothing relative to a descriptor, every call
+    takes the full path.  Manifests and meta use full paths.
     """
 
     #: how long compare_and_put_meta spins on another LIVE process's
@@ -612,52 +647,149 @@ class FileStore(BaseStore):
         self.root = root
         self.compress = compress
         self.fsync = fsync
-        os.makedirs(os.path.join(root, "pods"), exist_ok=True)
+        self._pods_dir = os.path.join(root, "pods")
+        os.makedirs(self._pods_dir, exist_ok=True)
         os.makedirs(os.path.join(root, "manifests"), exist_ok=True)
         os.makedirs(os.path.join(root, "meta"), exist_ok=True)
+        #: descriptors of ``pods/``: the last is held, earlier ones are
+        #: directories replaced underneath, kept open until the store is
+        #: dropped so a reader racing the swap never resolves a name
+        #: against a reused descriptor number.
+        self._pods_fds: List[int] = []
+        if _RELATIVE_IO:
+            self._pods_fds.append(os.open(self._pods_dir, _DIR_FLAGS))
+            weakref.finalize(self, _close_all, self._pods_fds)
+        #: shard directories this instance has made or found
+        self._shards: Set[str] = set()
+        self._repin_lock = threading.Lock()
 
     # -- atomic write primitive -------------------------------------------
-    def _write_atomic(self, path: str, data: bytes) -> None:
+    def _write_atomic(self, path: str, data: bytes,
+                      dir_fd: Optional[int] = None) -> None:
+        """Write `data` to `path` (relative to `dir_fd` if given) through
+        ``<path>.tmp`` and an atomic rename."""
         tmp = path + ".tmp"
-        with open(tmp, "wb") as f:
-            f.write(data)
+        fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o666,
+                     dir_fd=dir_fd)
+        try:
+            view = memoryview(data)
+            while view:
+                view = view[os.write(fd, view):]
             if self.fsync:
-                f.flush()
-                os.fsync(f.fileno())
-        os.replace(tmp, path)  # atomic: crash-safe (fault tolerance)
+                os.fsync(fd)
+        finally:
+            os.close(fd)
+        # atomic: crash-safe (fault tolerance)
+        os.replace(tmp, path, src_dir_fd=dir_fd, dst_dir_fd=dir_fd)
         if self.fsync:
-            dfd = os.open(os.path.dirname(path), os.O_RDONLY)
+            dfd = os.open(os.path.dirname(path), os.O_RDONLY, dir_fd=dir_fd)
             try:
                 os.fsync(dfd)
             finally:
                 os.close(dfd)
 
-    def _pod_path(self, digest_hex: str) -> str:
-        d = os.path.join(self.root, "pods", digest_hex[:2])
-        return os.path.join(d, digest_hex + ".pod")
+    # -- pod files: <shard>/<digest><suffix> under pods/ -------------------
+    def _at(self, digest_hex: str,
+            suffix: str) -> Tuple[str, Optional[int]]:
+        """(name, dir_fd) of one pod file for the `os` calls: relative to
+        the held ``pods/`` descriptor, or the full path where the
+        platform resolves no name against a descriptor."""
+        name = digest_hex[:2] + "/" + digest_hex + suffix
+        if self._pods_fds:
+            return name, self._pods_fds[-1]
+        return os.path.join(self._pods_dir, name), None
 
-    def _delta_path(self, digest_hex: str) -> str:
-        # delta form lives beside the whole form in the same shard dir;
-        # ".dpod" does not match the "*.pod" suffix test, so each listing
-        # sees only its own physical form.
-        d = os.path.join(self.root, "pods", digest_hex[:2])
-        return os.path.join(d, digest_hex + ".dpod")
+    def _repin(self, dir_fd: Optional[int]) -> bool:
+        """After a pod call found a name missing under `dir_fd`: if that
+        ``pods/`` was removed underneath (no link left), remake it, hold
+        the new one and forget the shards made.  True if the held
+        descriptor is no longer `dir_fd`."""
+        if dir_fd is None:
+            return False
+        if os.fstat(dir_fd).st_nlink:
+            return dir_fd != self._pods_fds[-1]
+        with self._repin_lock:
+            if dir_fd == self._pods_fds[-1]:
+                os.makedirs(self._pods_dir, exist_ok=True)
+                self._pods_fds.append(os.open(self._pods_dir, _DIR_FLAGS))
+                self._shards.clear()
+        return True
+
+    def _pod_call(self, fn, digest_hex: str, suffix: str, *args):
+        """``fn(name, *args, dir_fd=)`` on one pod file, run once more
+        against a remade ``pods/`` where the held one was removed."""
+        name, dir_fd = self._at(digest_hex, suffix)
+        try:
+            return fn(name, *args, dir_fd=dir_fd)
+        except FileNotFoundError:
+            if not self._repin(dir_fd):
+                raise
+        name, dir_fd = self._at(digest_hex, suffix)
+        return fn(name, *args, dir_fd=dir_fd)
+
+    def _exists(self, digest_hex: str, suffix: str) -> bool:
+        self.stats.pod_fs_calls += 1
+        try:
+            self._pod_call(os.stat, digest_hex, suffix)
+        except (OSError, ValueError):
+            return False
+        return True
+
+    def _read(self, digest_hex: str, suffix: str) -> bytes:
+        fd = self._pod_call(os.open, digest_hex, suffix, os.O_RDONLY)
+        with open(fd, "rb") as f:
+            return f.read()
+
+    def _nbytes(self, digest_hex: str, suffix: str) -> int:
+        return self._pod_call(os.stat, digest_hex, suffix).st_size
+
+    def _delete(self, digest_hex: str, suffix: str) -> None:
+        # single unlink: atomic at the filesystem level, so a crash either
+        # leaves the pod intact or fully gone — never truncated (the same
+        # guarantee os.replace gives the write path).  Empty shard dirs are
+        # left behind deliberately: removing them could race a concurrent
+        # put's mkdir.
+        self._pod_call(os.unlink, digest_hex, suffix)
+
+    def _put(self, digest_hex: str, suffix: str, data: bytes) -> None:
+        """Make the shard directory once per instance, then write through
+        `_write_atomic`.  A name found missing (shard or ``pods/``
+        removed underneath, or the tmp swept) repins and writes once
+        more: Linux creates nothing in a removed directory, so a write
+        never lands where no reader looks."""
+        for retry in (False, True):
+            name, dir_fd = self._at(digest_hex, suffix)
+            try:
+                shard = digest_hex[:2]
+                if shard not in self._shards:
+                    self.stats.pod_fs_calls += 1
+                    try:
+                        os.mkdir(os.path.dirname(name), dir_fd=dir_fd)
+                    except FileExistsError:
+                        pass
+                    self._shards.add(shard)
+                self.stats.pod_fs_calls += 3 if self.fsync else 2
+                self._write_atomic(name, data, dir_fd)
+                return
+            except FileNotFoundError:
+                if retry:
+                    raise
+                self._shards.clear()
+                os.makedirs(self._pods_dir, exist_ok=True)
+                self._repin(dir_fd)
 
     def _has_whole(self, digest_hex: str) -> bool:
-        return os.path.exists(self._pod_path(digest_hex))
+        return self._exists(digest_hex, ".pod")
 
     def _put_raw(self, digest_hex: str, data: bytes) -> None:
-        path = self._pod_path(digest_hex)
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        self._write_atomic(path, data)
+        self._put(digest_hex, ".pod", data)
 
     def _get_raw(self, digest_hex: str) -> bytes:
-        with open(self._pod_path(digest_hex), "rb") as f:
-            return f.read()
+        return self._read(digest_hex, ".pod")
 
     def _list_suffix(self, suffix: str) -> List[str]:
         out: List[str] = []
-        pods_dir = os.path.join(self.root, "pods")
+        pods_dir = self._pods_dir
         for shard in sorted(os.listdir(pods_dir)):
             sd = os.path.join(pods_dir, shard)
             if not os.path.isdir(sd):
@@ -671,33 +803,28 @@ class FileStore(BaseStore):
         return self._list_suffix(".pod")
 
     def _whole_nbytes(self, digest_hex: str) -> int:
-        return os.path.getsize(self._pod_path(digest_hex))
+        return self._nbytes(digest_hex, ".pod")
 
     def _delete_raw(self, digest_hex: str) -> None:
-        # single unlink: atomic at the filesystem level, so a crash either
-        # leaves the pod intact or fully gone — never truncated (the same
-        # guarantee os.replace gives the write path).  Empty shard dirs are
-        # left behind deliberately: removing them could race a concurrent
-        # _put_raw's makedirs.
-        os.remove(self._pod_path(digest_hex))
+        self._delete(digest_hex, ".pod")
 
+    # the delta form lives beside the whole form in the same shard dir;
+    # ".dpod" does not match the ".pod" suffix test, so each listing sees
+    # only its own physical form.
     def _has_delta(self, digest_hex: str) -> bool:
-        return os.path.exists(self._delta_path(digest_hex))
+        return self._exists(digest_hex, ".dpod")
 
     def _put_delta_raw(self, digest_hex: str, data: bytes) -> None:
-        path = self._delta_path(digest_hex)
-        os.makedirs(os.path.dirname(path), exist_ok=True)
-        self._write_atomic(path, data)
+        self._put(digest_hex, ".dpod", data)
 
     def _get_delta_raw(self, digest_hex: str) -> bytes:
-        with open(self._delta_path(digest_hex), "rb") as f:
-            return f.read()
+        return self._read(digest_hex, ".dpod")
 
     def _delete_delta_raw(self, digest_hex: str) -> None:
-        os.remove(self._delta_path(digest_hex))
+        self._delete(digest_hex, ".dpod")
 
     def _delta_nbytes(self, digest_hex: str) -> int:
-        return os.path.getsize(self._delta_path(digest_hex))
+        return self._nbytes(digest_hex, ".dpod")
 
     def list_delta_pods(self) -> List[str]:
         return self._list_suffix(".dpod")

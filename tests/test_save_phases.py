@@ -21,7 +21,7 @@ BODY_PHASES = ("avf", "digest", "observe", "podding", "decide", "intent",
 NEW_KEYS = ({"t_submit_wait", "t_queue", "t_body", "t_digest_dispatch",
              "t_digest_fetch", "t_digest_unpack", "t_digest_diff",
              "t_serialize", "t_store_put", "t_ns_wait", "bytes_fetched",
-             "bytes_dirty", "bytes_serialized"}
+             "bytes_dirty", "bytes_serialized", "n_store_fs_calls"}
             | {f"t_{p}" for p in BODY_PHASES})
 
 
