@@ -1,7 +1,8 @@
 """Architecture registry: ``get_config("<arch-id>")`` / ``--arch`` ids."""
-from .base import (SHAPES, ArchConfig, EncoderSpec, MoESpec, RGLRUSpec,
-                   SSMSpec, ShapeCell, VLMSpec, applicable_shapes,
+from .base import (SHAPES, ArchConfig, EncoderSpec, MLASpec, MoESpec,
+                   RGLRUSpec, SSMSpec, ShapeCell, VLMSpec, applicable_shapes,
                    LONG_CONTEXT_OK)
+from .deepseek_v2_lite import CONFIG as DEEPSEEK_V2_LITE
 from .falcon_mamba_7b import CONFIG as FALCON_MAMBA_7B
 from .granite_moe_3b_a800m import CONFIG as GRANITE_MOE_3B_A800M
 from .kimi_k2_1t_a32b import CONFIG as KIMI_K2_1T_A32B
@@ -17,7 +18,7 @@ ARCHS = {
     c.arch_id: c for c in [
         QWEN1_5_0_5B, QWEN2_5_14B, STARCODER2_3B, STARCODER2_7B,
         QWEN2_VL_2B, FALCON_MAMBA_7B, KIMI_K2_1T_A32B, GRANITE_MOE_3B_A800M,
-        WHISPER_BASE, RECURRENTGEMMA_9B,
+        WHISPER_BASE, RECURRENTGEMMA_9B, DEEPSEEK_V2_LITE,
     ]
 }
 

@@ -18,6 +18,44 @@ class MoESpec:
     expert_ff: int
     n_shared: int = 0
     capacity_factor: float = 1.25
+    #: DeepSeekMoE routing (`models.moe.routed_experts`): a softmax over
+    #: all `n_experts` in fp32, greedy top-k, weights not renormalized;
+    #: every routed token is computed (no capacity, no drops).  False:
+    #: GShard capacity dispatch (`moe_ffn`).
+    dropless: bool = False
+    #: routed experts held here, the first `n_held` (0: all `n_experts`)
+    n_held: int = 0
+    #: width of the shared experts' MLP held here (0: n_shared × expert_ff)
+    shared_ff: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+    @property
+    def shared_width(self) -> int:
+        return self.shared_ff or self.n_shared * self.expert_ff
+
+
+@dataclasses.dataclass(frozen=True)
+class MLASpec:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+    with YaRN rotary scaling (arXiv:2309.00071)."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    q_lora_rank: Optional[int] = None      # only None is implemented
+    yarn_factor: float = 1.0               # 1: plain RoPE
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 1.0
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +108,7 @@ class ArchConfig:
     first_dense_layers: int = 0      # MoE models: leading dense layers
     first_dense_ff: int = 0
     moe: Optional[MoESpec] = None
+    mla: Optional[MLASpec] = None
     ssm: Optional[SSMSpec] = None
     rglru: Optional[RGLRUSpec] = None
     encoder: Optional[EncoderSpec] = None
@@ -106,7 +145,7 @@ class ArchConfig:
             elif self.family == "moe":
                 assert self.moe is not None
                 ffn = "dense_first" if i < self.first_dense_layers else "moe"
-                plan.append(("attn", ffn))
+                plan.append(("mla" if self.mla else "attn", ffn))
             else:  # dense / vlm / encdec decoder
                 plan.append(("attn", self.mlp))
         return plan
@@ -123,12 +162,16 @@ class ArchConfig:
             head_dim=32,
         )
         if self.moe is not None:
-            small["moe"] = MoESpec(n_experts=min(self.moe.n_experts, 8),
-                                   top_k=min(self.moe.top_k, 2),
-                                   expert_ff=64,
-                                   n_shared=min(self.moe.n_shared, 1))
+            small["moe"] = dataclasses.replace(
+                self.moe, n_experts=min(self.moe.n_experts, 8),
+                top_k=min(self.moe.top_k, 2), expert_ff=64,
+                n_shared=min(self.moe.n_shared, 1), n_held=0, shared_ff=0)
             small["first_dense_layers"] = min(self.first_dense_layers, 1)
             small["first_dense_ff"] = 256 if self.first_dense_layers else 0
+        if self.mla is not None:
+            small["mla"] = dataclasses.replace(
+                self.mla, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16)
         if self.ssm is not None:
             small["ssm"] = SSMSpec(expand=2, d_state=4, d_conv=4, dt_rank=8,
                                    chunk=8)
@@ -143,6 +186,42 @@ class ArchConfig:
             small["vlm"] = VLMSpec(n_patches=16, grid=(4, 4),
                                    mrope_sections=(4, 6, 6))
         return dataclasses.replace(self, **small)
+
+    def chip_share(self, group: int, n_layers: int) -> "ArchConfig":
+        """What the first chip of a `group`-chip deployment that shares
+        every layer holds, at every published width: routed experts by
+        expert parallelism (the first `n_experts / group` of them),
+        attention heads, the dense and shared MLPs' widths and the
+        vocabulary by tensor parallelism (a `1 / group` slice each; the
+        latent projection and norms are replicated), over the first
+        `n_layers` layers (the rest lie on further pipeline stages).  The
+        router keeps all its outputs and its experts per token; what the
+        other chips would add to a layer's output is left out."""
+        def part(n: int, what: str) -> int:
+            if n % group:
+                raise ValueError(f"{what} {n} does not divide over "
+                                 f"{group} chips")
+            return n // group
+
+        fields: Dict = dict(
+            n_layers=n_layers,
+            n_heads=part(self.n_heads, "heads"),
+            n_kv_heads=part(self.n_kv_heads, "kv heads"),
+            vocab=part(self.vocab, "vocabulary"),
+            d_ff=part(self.d_ff, "MLP width"),
+        )
+        if self.first_dense_layers:
+            fields["first_dense_ff"] = part(self.first_dense_ff,
+                                            "dense MLP width")
+        if self.moe is not None:
+            held = part(self.moe.n_experts, "routed experts")
+            fields["moe"] = dataclasses.replace(
+                self.moe, n_held=held,
+                shared_ff=(part(self.moe.shared_width, "shared MLP width")
+                           if self.moe.n_shared else 0))
+        if self.head_dim == 0:
+            fields["head_dim"] = self.hd     # keep the published head size
+        return dataclasses.replace(self, **fields)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -76,6 +76,10 @@ class ChangeReport:
     skipped_chunks: int = 0
     n_syncs: int = 0                   # blocking device fetches this save
     dirty_bytes: int = 0               # true lengths of the dirty chunks
+    #: leaves with some chunks dirty and some clean: their dirty bytes,
+    #: and their whole size
+    partly_dirty_bytes: int = 0
+    bytes_in_partly_dirty: int = 0
     #: speculatively prefetched payload bytes (chunk key -> exact bytes),
     #: compacted into the digest fetch by the fused path
     payload: Dict[str, bytes] = dataclasses.field(default_factory=dict)
@@ -267,6 +271,8 @@ class ChangeDetector:
         digests: Dict[str, bytes] = {}
         dirty: Set[str] = set()
         dirty_bytes = 0
+        leaf_bytes: Dict[str, int] = {}
+        leaf_dirty: Dict[str, List[int]] = {}   # [chunks, bytes] dirty
         new_keys: List[str] = []
         new_rows: List[int] = []        # rows into res.mat (or ~row into table)
         seen_leaves: Dict[str, int] = {}
@@ -275,6 +281,7 @@ class ChangeDetector:
             key = node.key
             lkey = "/".join(node.path)
             seen_leaves[lkey] = seen_leaves.get(lkey, 0) + 1
+            leaf_bytes[lkey] = leaf_bytes.get(lkey, 0) + node.size
             if lkey in active_leaf_set:
                 active += 1
                 r = res.row_of(lkey, node.chunk_index)
@@ -282,6 +289,9 @@ class ChangeDetector:
                 if changed[r]:
                     dirty.add(key)
                     dirty_bytes += node.size
+                    tally = leaf_dirty.setdefault(lkey, [0, 0])
+                    tally[0] += 1
+                    tally[1] += node.size
                 new_keys.append(key)
                 new_rows.append(r)
             else:
@@ -296,8 +306,13 @@ class ChangeDetector:
                     digests[key] = buf[16 * r:16 * (r + 1)]
                     dirty.add(key)
                     dirty_bytes += node.size
+                    tally = leaf_dirty.setdefault(lkey, [0, 0])
+                    tally[0] += 1
+                    tally[1] += node.size
                     new_keys.append(key)
                     new_rows.append(r)
+        partly = [(lkey, n_bytes) for lkey, (n, n_bytes) in leaf_dirty.items()
+                  if n < seen_leaves[lkey]]
 
         # 4. persist: gather new table rows vectorized (fresh rows from
         # res.mat, inherited rows from the old table).  The compact table
@@ -321,6 +336,9 @@ class ChangeDetector:
         return ChangeReport(digests=digests, dirty=dirty,
                             active_chunks=active, skipped_chunks=skipped,
                             n_syncs=res.n_syncs, dirty_bytes=dirty_bytes,
+                            partly_dirty_bytes=sum(b for _, b in partly),
+                            bytes_in_partly_dirty=sum(
+                                leaf_bytes[lkey] for lkey, _ in partly),
                             payload=payload,
                             n_spec_hits=hits,
                             n_spec_misses=len(dirty) - hits,

@@ -681,6 +681,8 @@ class Chipmink:
             stats["n_chunks"] = len(report.digests)
             stats["n_dirty_chunks"] = len(report.dirty)
             stats["bytes_dirty"] = report.dirty_bytes
+            stats["bytes_partly_dirty"] = report.partly_dirty_bytes
+            stats["bytes_in_partly_dirty"] = report.bytes_in_partly_dirty
             stats["n_digest_syncs"] = report.n_syncs
             stats["n_spec_predicted"] = len(spec) if spec else 0
             stats["n_spec_hits"] = report.n_spec_hits

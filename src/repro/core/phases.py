@@ -26,10 +26,12 @@ Phases of one save (``save_stats`` key in brackets):
                   (``t_commit``)
 
 Counters: ``bytes_fetched`` (every byte brought from device to host),
-``bytes_dirty`` (true lengths of the dirty chunks), ``bytes_serialized``
-(pod bytes serialized), ``n_store_fs_calls`` (the store's
-``pod_fs_calls`` across the write phase: path-resolving filesystem
-calls of its pod probes and writes).
+``bytes_dirty`` (true lengths of the dirty chunks),
+``bytes_partly_dirty`` and ``bytes_in_partly_dirty`` (of the leaves with
+some chunks dirty and some clean: their dirty bytes, and their whole
+size), ``bytes_serialized`` (pod bytes serialized),
+``n_store_fs_calls`` (the store's ``pod_fs_calls`` across the write
+phase: path-resolving filesystem calls of its pod probes and writes).
 """
 from __future__ import annotations
 

@@ -234,6 +234,35 @@ def _packer_for(plan: BatchPlan):
     return jax.jit(pack)
 
 
+@functools.lru_cache(maxsize=512)
+def _gather_for(rows: int, width: int, n: int):
+    """Compiled: `n` rows of a packed bucket of `rows` x `width` words,
+    by their int32 indices."""
+    words = jax.ShapeDtypeStruct((rows, width), jnp.uint32)
+    idx = jax.ShapeDtypeStruct((n,), jnp.int32)
+    return jax.jit(lambda w, i: w[i]).lower(words, idx).compile()
+
+
+@functools.lru_cache(maxsize=512)
+def _gathers_for(plan: BatchPlan) -> Tuple[Dict[int, Any], ...]:
+    """Per bucket, {n: the compiled gather of n packed rows} for every
+    power of two n up to the most rows the bucket can send sparse.
+
+    Built for every bucket the first time the plan speculates: a set of
+    speculated rows is gathered in one call, padded to a power of two,
+    so no later speculation set compiles a program."""
+    out = []
+    for b in plan.buckets:
+        # a leaf crosses sparse with fewer than half its chunks wanted
+        # (`fetch_whole_leaf`)
+        most = sum((s.n_chunks - 1) // 2 for s in plan.leaves
+                   if s.bucket == b.width)
+        sizes = pow2ceil(most).bit_length() if most else 0
+        out.append({1 << k: _gather_for(b.padded_rows, b.width, 1 << k)
+                    for k in range(sizes)})
+    return tuple(out)
+
+
 def _pack_leaf_words_np(slot: LeafSlot, arr: np.ndarray) -> np.ndarray:
     from .ops import to_words_np
     w = to_words_np(arr)
@@ -464,8 +493,8 @@ def _dispatch_device_fused(plan: BatchPlan, arrays: Sequence[Any], *,
     gathered from the packed bucket into one dense buffer — rows are the
     chunks' little-endian word streams, so a row's first true-length
     bytes ARE the payload — padded to a power-of-two row count
-    (repeating the first row) so the gather's jit cache stops recompiling
-    as the speculation set fluctuates.
+    (repeating the first row) by a gather compiled ahead for that count
+    (`_gathers_for`), so a changing speculation set compiles nothing.
 
     Returns (device outputs for the ONE `jax.device_get`, unpack: fetched
     outputs -> (digest mats, dirty masks, {chunk key: payload bytes}, new
@@ -475,6 +504,7 @@ def _dispatch_device_fused(plan: BatchPlan, arrays: Sequence[Any], *,
     lengths = _plan_lengths(plan)
     base = np.cumsum([0] + [b.n_rows for b in plan.buckets])
     packed = list(_packer_for(plan)(*arrays))
+    gather = _gathers_for(plan) if spec_rows else ()
     digs_dev: List[Any] = []
     masks_dev: List[Any] = []
     # (bucket index, gathered rows) and the gathered device buffers
@@ -516,10 +546,12 @@ def _dispatch_device_fused(plan: BatchPlan, arrays: Sequence[Any], *,
                 sparse.append(rows[lo:hi])
         if sparse:
             rows = np.concatenate(sparse)
-            pad = np.full(pow2ceil(len(rows)) - len(rows), rows[0])
-            idx = jnp.asarray(np.concatenate([rows, pad]), jnp.int32)
+            n = pow2ceil(len(rows))
+            pad = np.full(n - len(rows), rows[0])
+            # int32 on the host: a cast on the device would compile per size
+            idx = jnp.asarray(np.concatenate([rows, pad]).astype(np.int32))
             gathers.append((bi, rows))
-            gathered.append(words[idx])
+            gathered.append(gather[bi][n](words, idx))
     del words
     # padded digest rows stay on device as the next save's prev table:
     # every digested row is trusted once fetched.

@@ -44,10 +44,12 @@ def _mask_bias(q_pos: jax.Array, k_pos: jax.Array, *, causal: bool,
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, window: Optional[int] = None,
               q_chunk: Optional[int] = None,
-              q_offset: int = 0, mixed: bool = False) -> jax.Array:
+              q_offset: int = 0, mixed: bool = False,
+              scale: Optional[float] = None) -> jax.Array:
     """Full (or q-chunked) grouped attention.
 
-    q: (B, S, Hq, D); k, v: (B, T, Hkv, D).  Returns (B, S, Hq, D).
+    q, k: (B, S|T, Hq|Hkv, D); v: (B, T, Hkv, Dv).  Returns (B, S, Hq,
+    Dv).  `scale` multiplies the scores (default D ** -0.5).
     `q_offset` positions the queries within the key timeline (prefill
     continuation).  `q_chunk` bounds the per-step score materialization to
     (B, Hq, q_chunk, T) — the long-context memory lever.  `mixed=True`
@@ -58,8 +60,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     B, S, Hq, D = q.shape
     _, T, Hkv, _ = k.shape
     G = Hq // Hkv
+    Dv = v.shape[-1]
     qg = q.reshape(B, S, Hkv, G, D)
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     k_pos = jnp.arange(T)
 
     def block(q_blk: jax.Array, offset: int) -> jax.Array:
@@ -76,7 +79,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scores = scores + bias[None, None, None, :, :]
         w = jax.nn.softmax(scores, axis=-1)
         o = jnp.einsum("bngst,btnd->bsngd", w.astype(v.dtype), v)
-        return o.reshape(B, s, Hq, D)
+        return o.reshape(B, s, Hq, Dv)
 
     if q_chunk is None or S <= q_chunk:
         return block(qg, 0)
@@ -100,7 +103,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      lengths: jax.Array, *,
-                     window: Optional[int] = None) -> jax.Array:
+                     window: Optional[int] = None,
+                     scale: Optional[float] = None) -> jax.Array:
     """One-token attention over a KV cache.
 
     q: (B, 1, Hq, D); caches: (B, T, Hkv, D); lengths: (B,) valid entries.
@@ -113,7 +117,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     _, T, Hkv, _ = k_cache.shape
     G = Hq // Hkv
     qg = q.reshape(B, Hkv, G, D)
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
     scores = jnp.einsum("bngd,btnd->bngt", qg.astype(jnp.float32) * scale,
                         k_cache.astype(jnp.float32))
     idx = jnp.arange(T)[None, :]                       # (1, T)
@@ -121,4 +125,4 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     o = jnp.einsum("bngt,btnd->bngd", w.astype(v_cache.dtype), v_cache)
-    return o.reshape(B, 1, Hq, D)
+    return o.reshape(B, 1, Hq, v_cache.shape[-1])

@@ -10,6 +10,7 @@ construction (the multi-pod dry-run — no allocation).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -129,14 +130,44 @@ def rope_freqs(head_dim: int, theta: float = 10000.0) -> jax.Array:
     return 1.0 / (theta ** exponent)           # (head_dim/2,)
 
 
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature, 0.1 · mscale · ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, factor: float,
+               original_max_position: int, beta_fast: float,
+               beta_slow: float) -> jax.Array:
+    """YaRN's rotary frequencies [arXiv:2309.00071], as DeepSeek-V2 sets
+    them: lanes that turn more than `beta_fast` times over the original
+    context keep their frequency, lanes that turn fewer than `beta_slow`
+    times are divided by `factor`, and a linear ramp joins the two."""
+    def lane(rotations: float) -> float:
+        return (head_dim * math.log(original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(lane(beta_fast)), 0)
+    high = min(math.ceil(lane(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = rope_freqs(head_dim, theta)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
 def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float = 10000.0) -> jax.Array:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+               theta: float = 10000.0, inv_freq: Optional[jax.Array] = None,
+               mscale: float = 1.0) -> jax.Array:
+    """x: (..., S, H, D); positions: broadcastable to (..., S).  Rotary
+    pairs are (i, i + D/2).  `inv_freq` (D/2,) replaces the plain
+    frequencies of `theta` (YaRN); `mscale` multiplies cos and sin."""
     D = x.shape[-1]
-    inv = rope_freqs(D, theta)                                # (D/2,)
+    inv = rope_freqs(D, theta) if inv_freq is None else inv_freq  # (D/2,)
     ang = positions[..., None].astype(jnp.float32) * inv      # (..., S, D/2)
-    cos = jnp.cos(ang)[..., None, :]                          # (..., S, 1, D/2)
-    sin = jnp.sin(ang)[..., None, :]
+    cos = mscale * jnp.cos(ang)[..., None, :]                 # (..., S, 1, D/2)
+    sin = mscale * jnp.sin(ang)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -1,5 +1,13 @@
-"""Mixture-of-Experts FFN: top-k routing with capacity-factor dispatch
-(GShard-style dense einsum formulation) + optional shared expert.
+"""Mixture-of-Experts FFN, two ways.
+
+`moe_ffn`: top-k routing with capacity-factor dispatch (GShard-style
+dense einsum formulation) + optional shared expert; it computes every
+expert and drops tokens over capacity (granite, kimi).
+
+`routed_experts`: DeepSeekMoE routing over all experts, computed for the
+experts this chip holds only, with no capacity and no drop: the routed
+rows are sorted by expert and run through grouped matmuls
+(`jax.lax.ragged_dot`), sized for the most rows a token can send.
 
 Expert weights carry an `experts` leading logical axis (expert parallelism:
 sharded over the `model` mesh axis); tokens are grouped along the data
@@ -18,8 +26,13 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from .layers import dense, swiglu
+
+
+#: name of the routing decisions that a rematerialized layer keeps
+ROUTING = "moe_routing"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,9 +89,12 @@ def moe_ffn(x: jax.Array, params: Dict, cfg: MoEConfig
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x: (B, S, E) → (y, aux_loss, expert_token_counts (X,)).
 
-    The per-expert token counts feed Chipmink's active-variable filter:
-    experts with zero routed tokens this window received no gradient, so
-    their parameter/optimizer pods are provably clean.
+    The per-expert token counts are the step's touch report.  An expert
+    that got no token this step got a zero gradient, but it is not
+    clean: AdamW still moves its rows (weight decay, and the decayed
+    moments of earlier steps), so only a train-step mask
+    (`make_train_step(expert_mask=...)`) keeps an expert's rows as they
+    were.
     """
     B, S, E = x.shape
     G = min(cfg.n_groups, B * S)
@@ -108,3 +124,53 @@ def moe_ffn(x: jax.Array, params: Dict, cfg: MoEConfig
 
     counts = jnp.sum(dispatch.astype(jnp.float32), axis=(0, 1, 3))  # (X,)
     return y, aux, counts
+
+
+def routed_experts(x: jax.Array, params: Dict,
+                   spec) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """DeepSeekMoE's routed experts (DeepSeek-V2, arXiv:2405.04434 §2.2),
+    the part of them this chip holds.
+
+    x: (B, S, E); params: `router` (E, X) over all X = `spec.n_experts`
+    experts, and the held experts' `w_gate`, `w_up` (H, E, F) and
+    `w_down` (H, F, E), experts 0 .. H - 1.  The router
+    is a softmax over X in fp32, greedy top-k, weights not renormalized
+    (DeepSeek-V2-Lite's routed scaling factor is 1).  Each (token,
+    choice) that names a held expert is computed; the rest are what the
+    other chips add, and are left out.  Returns (y (B, S, E), routed rows
+    per held expert (H,), the experts each token chose (B, S, k)).
+    """
+    B, S, E = x.shape
+    N, k, H = B * S, spec.top_k, spec.held
+    tokens = x.reshape(N, E)
+    with jax.named_scope("moe_router"):
+        logits = jnp.einsum("ne,ex->nx", tokens.astype(jnp.float32),
+                            params["router"].astype(jnp.float32))
+        weight, choice = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        weight = checkpoint_name(weight, ROUTING)
+        choice = checkpoint_name(choice, ROUTING)
+    with jax.named_scope("moe_experts"):
+        local = choice.reshape(-1)                             # (N k,)
+        held = local < H
+        slot = jnp.where(held, local, H)      # the others sort last
+        order = jnp.argsort(slot, stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(slot, H, dtype=jnp.int32), axis=0)
+        # ragged_dot leaves the rows past the last group unspecified (on a
+        # TPU they keep what the buffer held, NaN included): each operand
+        # and product is selected to the held rows, so such a value reaches
+        # neither the output nor, through the selects' transposes, a
+        # gradient
+        valid = (jnp.arange(N * k) < jnp.sum(sizes))[:, None]
+
+        def grouped(a, w):
+            return jnp.where(valid, jax.lax.ragged_dot(a, w, sizes), 0)
+
+        rows = jnp.where(valid, jnp.take(tokens, order // k, axis=0), 0)
+        g = grouped(rows, params["w_gate"])
+        u = grouped(rows, params["w_up"])
+        out = grouped(jax.nn.silu(g) * u, params["w_down"])
+        out = jnp.take(out, jnp.argsort(order), axis=0).reshape(N, k, E)
+        w = jnp.where(held.reshape(N, k), weight, 0.0)
+        y = jnp.einsum("nke,nk->ne", out.astype(jnp.float32), w)
+    return (y.reshape(B, S, E).astype(x.dtype), sizes,
+            choice.reshape(B, S, k))

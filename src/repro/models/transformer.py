@@ -2,7 +2,7 @@
 families (whisper's enc-dec lives in whisper.py and reuses these pieces).
 
 Each layer is (mixer, ffn) from `cfg.layer_plan()`:
-    mixer ∈ {attn, attn_local, mamba, rglru}
+    mixer ∈ {attn, attn_local, mla, mamba, rglru}
     ffn   ∈ {swiglu, gelu, moe, dense_first, none}
 
 Parameters are declared once in `param_defs` (shape + dtype + logical axes
@@ -25,8 +25,9 @@ from ..configs.base import ArchConfig
 from ..parallel.sharding import constrain
 from .attention import attention, decode_attention
 from .layers import (ParamDef, ParamDefs, apply_mrope, apply_rope, dense,
-                     gelu_mlp, layer_norm, rms_norm, swiglu)
-from .moe import MoEConfig, moe_ffn
+                     gelu_mlp, layer_norm, rms_norm, swiglu, yarn_freqs,
+                     yarn_mscale)
+from .moe import ROUTING, MoEConfig, moe_ffn, routed_experts
 from .rglru import (RGLRUConfig, recurrent_block, recurrent_block_decode)
 from .ssm import SSMConfig, mamba_block, mamba_decode_step
 
@@ -59,6 +60,26 @@ def _attn_defs(p: Tuple[str, ...], cfg: ArchConfig) -> ParamDefs:
     return defs
 
 
+def _mla_defs(p: Tuple[str, ...], cfg: ArchConfig) -> ParamDefs:
+    a = cfg.mla
+    if a.q_lora_rank is not None:
+        raise NotImplementedError("MLA with a q-LoRA projection")
+    E, H, R = cfg.d_model, cfg.n_heads, a.kv_lora_rank
+    return {
+        p + ("q_proj",): ParamDef((E, H * a.qk_head_dim), None,
+                                  ("embed", "heads")),
+        p + ("kv_a_proj_with_mqa",): ParamDef(
+            (E, R + a.qk_rope_head_dim), None, ("embed", None)),
+        p + ("kv_a_layernorm", "scale"): ParamDef((R,), jnp.float32, (None,),
+                                                  "zeros"),
+        p + ("kv_b_proj",): ParamDef(
+            (R, H * (a.qk_nope_head_dim + a.v_head_dim)), None,
+            (None, "heads")),
+        p + ("o_proj",): ParamDef((H * a.v_head_dim, E), None,
+                                  ("heads", "embed")),
+    }
+
+
 def _ffn_defs(p: Tuple[str, ...], cfg: ArchConfig, kind: str) -> ParamDefs:
     E = cfg.d_model
     if kind == "swiglu" or kind == "dense_first":
@@ -76,6 +97,20 @@ def _ffn_defs(p: Tuple[str, ...], cfg: ArchConfig, kind: str) -> ParamDefs:
             p + ("w_down",): ParamDef((F, E), None, ("ffn", "embed")),
             p + ("b_down",): ParamDef((E,), None, (None,), "zeros"),
         }
+    if kind == "moe" and cfg.moe.dropless:
+        m = cfg.moe
+        X, H, F, Fs = m.n_experts, m.held, m.expert_ff, m.shared_width
+        defs = {
+            p + ("router",): ParamDef((E, X), None, ("embed", None)),
+            p + ("w_gate",): ParamDef((H, E, F), None, ("experts", "embed", None)),
+            p + ("w_up",): ParamDef((H, E, F), None, ("experts", "embed", None)),
+            p + ("w_down",): ParamDef((H, F, E), None, ("experts", None, "embed")),
+        }
+        if m.n_shared:
+            defs[p + ("shared_gate",)] = ParamDef((E, Fs), None, ("embed", "ffn"))
+            defs[p + ("shared_up",)] = ParamDef((E, Fs), None, ("embed", "ffn"))
+            defs[p + ("shared_down",)] = ParamDef((Fs, E), None, ("ffn", "embed"))
+        return defs
     if kind == "moe":
         m = cfg.moe
         assert m is not None
@@ -160,6 +195,8 @@ def param_defs(cfg: ArchConfig) -> ParamDefs:
         defs.update(_norm_defs(p + ("norm1",), cfg))
         if mixer in ("attn", "attn_local"):
             defs.update(_attn_defs(p + ("attn",), cfg))
+        elif mixer == "mla":
+            defs.update(_mla_defs(p + ("attn",), cfg))
         elif mixer == "mamba":
             defs.update(_mamba_defs(p + ("mamba",), cfg))
         elif mixer == "rglru":
@@ -248,6 +285,89 @@ def _attn_apply(params: Dict, x: jax.Array, cfg: ArchConfig, *,
     return dense(o.reshape(B, S, Hq * D), params["wo"])
 
 
+def _mla_rope(cfg: ArchConfig) -> Tuple[jax.Array, float, float]:
+    """(rotary frequencies, cos/sin scale, softmax scale) of an MLA layer:
+    YaRN over the rope head size, and DeepSeek-V2's softmax scale
+    qk_head_dim^-1/2 · mscale(factor, mscale_all_dim)^2."""
+    a = cfg.mla
+    inv = yarn_freqs(a.qk_rope_head_dim, cfg.rope_theta, a.yarn_factor,
+                     a.yarn_original_max_position, a.yarn_beta_fast,
+                     a.yarn_beta_slow)
+    cos_scale = (yarn_mscale(a.yarn_factor, a.yarn_mscale)
+                 / yarn_mscale(a.yarn_factor, a.yarn_mscale_all_dim))
+    softmax_scale = (a.qk_head_dim ** -0.5
+                     * yarn_mscale(a.yarn_factor, a.yarn_mscale_all_dim) ** 2)
+    return inv, cos_scale, softmax_scale
+
+
+def _mla_qkv(params: Dict, x: jax.Array, cfg: ArchConfig,
+             positions: jax.Array):
+    """Queries (B, S, H, qk) and the latent cache entries of `x`: the
+    latent before its norm (B, S, R) and the rotated shared rope key
+    (B, S, rope)."""
+    a = cfg.mla
+    B, S, _ = x.shape
+    inv, cos_scale, _ = _mla_rope(cfg)
+    q = dense(x, params["q_proj"]).reshape(B, S, cfg.n_heads, a.qk_head_dim)
+    q_nope, q_pe = jnp.split(q, [a.qk_nope_head_dim], axis=-1)
+    q_pe = apply_rope(q_pe, positions, inv_freq=inv, mscale=cos_scale)
+    ckv, k_pe = jnp.split(dense(x, params["kv_a_proj_with_mqa"]),
+                          [a.kv_lora_rank], axis=-1)
+    k_pe = apply_rope(k_pe[:, :, None, :], positions, inv_freq=inv,
+                      mscale=cos_scale)[:, :, 0]
+    return jnp.concatenate([q_nope, q_pe], axis=-1), ckv, k_pe
+
+
+def _mla_kv(params: Dict, ckv: jax.Array, k_pe: jax.Array,
+            cfg: ArchConfig) -> Tuple[jax.Array, jax.Array]:
+    """Per-head keys (B, T, H, qk) and values (B, T, H, v) from the
+    latent: its norm, the up-projection `kv_b_proj`, and the one rope key
+    every head shares."""
+    a = cfg.mla
+    B, T, _ = ckv.shape
+    kv = dense(rms_norm(ckv, params["kv_a_layernorm"]["scale"]),
+               params["kv_b_proj"])
+    kv = kv.reshape(B, T, cfg.n_heads, a.qk_nope_head_dim + a.v_head_dim)
+    k_nope, v = jnp.split(kv, [a.qk_nope_head_dim], axis=-1)
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :].astype(k_nope.dtype),
+                            (B, T, cfg.n_heads, a.qk_rope_head_dim))
+    return jnp.concatenate([k_nope, k_pe], axis=-1), v
+
+
+def _mla_apply(params: Dict, x: jax.Array, cfg: ArchConfig, *,
+               positions: jax.Array, q_chunk: Optional[int]) -> jax.Array:
+    """Multi-head latent attention (DeepSeek-V2 §2.1) without q-LoRA, over
+    the heads this chip holds; rotary pairs (i, i + rope/2)."""
+    B, S, _ = x.shape
+    with jax.named_scope("mla"):
+        q, ckv, k_pe = _mla_qkv(params, x, cfg, positions)
+        k, v = _mla_kv(params, ckv, k_pe, cfg)
+        o = attention(q, k, v, causal=True, q_chunk=q_chunk,
+                      scale=_mla_rope(cfg)[2])
+        return dense(o.reshape(B, S, -1), params["o_proj"])
+
+
+def _ffn_apply(params: Dict, h: jax.Array, cfg: ArchConfig, ffn: str):
+    """A layer's feed-forward part → (output, touch report)."""
+    if ffn in ("swiglu", "dense_first"):
+        return swiglu(h, params["w_gate"], params["w_up"],
+                      params["w_down"]), {}
+    if ffn == "gelu":
+        return gelu_mlp(h, params["w_up"], params["b_up"],
+                        params["w_down"], params["b_down"]), {}
+    assert ffn == "moe", ffn
+    B, S, _ = h.shape
+    if not cfg.moe.dropless:
+        y, aux_loss, counts = moe_ffn(h, params, _moe_cfg(cfg, B * S))
+        return y, {"moe_aux": aux_loss, "expert_counts": counts}
+    y, counts, choice = routed_experts(h, params, cfg.moe)
+    if cfg.moe.n_shared:
+        with jax.named_scope("moe_shared"):
+            y = y + swiglu(h, params["shared_gate"], params["shared_up"],
+                           params["shared_down"])
+    return y, {"expert_counts": counts, "expert_choice": choice}
+
+
 def _layer_apply(params: Dict, x: jax.Array, cfg: ArchConfig, mixer: str,
                  ffn: str, *, positions, q_chunk, mrope_positions):
     aux = {}
@@ -259,24 +379,17 @@ def _layer_apply(params: Dict, x: jax.Array, cfg: ArchConfig, mixer: str,
         h = _attn_apply(params["attn"], h, cfg, positions=positions,
                         window=window, q_chunk=q_chunk,
                         mrope_positions=mrope_positions)
+    elif mixer == "mla":
+        h = _mla_apply(params["attn"], h, cfg, positions=positions,
+                       q_chunk=q_chunk)
     elif mixer == "mamba":
         h = mamba_block(params["mamba"], h, _ssm_cfg(cfg))
     elif mixer == "rglru":
         h = _recurrent_apply(params["rec"], h, cfg)
     x = x + h
     if ffn != "none":
-        h = _norm(x, params["norm2"], cfg)
-        if ffn in ("swiglu", "dense_first"):
-            h = swiglu(h, params["ffn"]["w_gate"], params["ffn"]["w_up"],
-                       params["ffn"]["w_down"])
-        elif ffn == "gelu":
-            h = gelu_mlp(h, params["ffn"]["w_up"], params["ffn"]["b_up"],
-                         params["ffn"]["w_down"], params["ffn"]["b_down"])
-        elif ffn == "moe":
-            B, S, _ = h.shape
-            h, aux_loss, counts = moe_ffn(h, params["ffn"],
-                                          _moe_cfg(cfg, B * S))
-            aux = {"moe_aux": aux_loss, "expert_counts": counts}
+        h, aux = _ffn_apply(params["ffn"], _norm(x, params["norm2"], cfg),
+                            cfg, ffn)
         x = x + h
     x = constrain(x, ("batch", "seq_model" if cfg.seq_sp else None, None))
     return x, aux
@@ -387,7 +500,11 @@ def forward(params: Dict, tokens: jax.Array, cfg: ArchConfig, *,
             p, y, cfg, mixer, ffn, positions=positions, q_chunk=q_chunk,
             mrope_positions=mrope_pos)
         if remat:
-            layer_fn = jax.checkpoint(layer_fn)
+            # the backward pass recomputes a layer but keeps its routing:
+            # a recomputed top-k could pick other experts than the loss's
+            layer_fn = jax.checkpoint(
+                layer_fn, policy=jax.checkpoint_policies.save_only_these_names(
+                    ROUTING))
         x, aux = layer_fn(params["layers"][str(i)], x)
         for k, v in aux.items():
             aux_all.setdefault(k, []).append(v)
@@ -423,7 +540,9 @@ def loss_fn(params: Dict, batch: Dict, cfg: ArchConfig, *,
         moe_loss = 1e-2 * jnp.mean(jnp.stack(aux["moe_aux"]))
         loss = loss + moe_loss
         metrics["moe_aux"] = moe_loss
-        metrics["expert_counts"] = jnp.stack(aux["expert_counts"])
+    for name in ("expert_counts", "expert_choice"):
+        if name in aux:
+            metrics[name] = jnp.stack(aux[name])
     return loss, metrics
 
 
@@ -441,6 +560,14 @@ def cache_spec(cfg: ArchConfig, B: int, T: int) -> Dict:
             layers[str(i)] = {
                 "k": jax.ShapeDtypeStruct((B, Tw, Hkv, D), jnp.bfloat16),
                 "v": jax.ShapeDtypeStruct((B, Tw, Hkv, D), jnp.bfloat16),
+            }
+        elif mixer == "mla":
+            a = cfg.mla
+            layers[str(i)] = {
+                "ckv": jax.ShapeDtypeStruct((B, T, a.kv_lora_rank),
+                                            jnp.bfloat16),
+                "k_pe": jax.ShapeDtypeStruct((B, T, a.qk_rope_head_dim),
+                                             jnp.bfloat16),
             }
         elif mixer == "attn_local":
             Tw = min(T, cfg.rglru.attn_window)
@@ -477,6 +604,9 @@ def cache_axes(cfg: ArchConfig) -> Dict:
         if mixer in ("attn", "attn_local"):
             layers[str(i)] = {"k": ("batch", "cache_t", None, None),
                               "v": ("batch", "cache_t", None, None)}
+        elif mixer == "mla":
+            layers[str(i)] = {"ckv": ("batch", "cache_t", None),
+                              "k_pe": ("batch", "cache_t", None)}
         elif mixer == "mamba":
             layers[str(i)] = {"conv": ("batch", None, "inner"),
                               "ssm": ("batch", "inner", None)}
@@ -510,6 +640,8 @@ def decode_step(params: Dict, cache: Dict, tokens: jax.Array,
         h = _norm(x, lp["norm1"], cfg)
         if mixer in ("attn", "attn_local"):
             h, new_lc = _decode_attn(lp["attn"], h, lcache, pos, cfg, mixer)
+        elif mixer == "mla":
+            h, new_lc = _decode_mla(lp["attn"], h, lcache, pos, cfg)
         elif mixer == "mamba":
             h, conv_s, ssm_s = mamba_decode_step(
                 lp["mamba"], h, lcache["conv"], lcache["ssm"], _ssm_cfg(cfg))
@@ -518,15 +650,8 @@ def decode_step(params: Dict, cache: Dict, tokens: jax.Array,
             h, new_lc = _decode_recurrent(lp["rec"], h, lcache, cfg)
         x = x + h
         if ffn != "none":
-            h = _norm(x, lp["norm2"], cfg)
-            if ffn in ("swiglu", "dense_first"):
-                h = swiglu(h, lp["ffn"]["w_gate"], lp["ffn"]["w_up"],
-                           lp["ffn"]["w_down"])
-            elif ffn == "gelu":
-                h = gelu_mlp(h, lp["ffn"]["w_up"], lp["ffn"]["b_up"],
-                             lp["ffn"]["w_down"], lp["ffn"]["b_down"])
-            elif ffn == "moe":
-                h, _aux, _counts = moe_ffn(h, lp["ffn"], _moe_cfg(cfg, B))
+            h, _aux = _ffn_apply(lp["ffn"], _norm(x, lp["norm2"], cfg), cfg,
+                                 ffn)
             x = x + h
         new_layers[str(i)] = new_lc
     x = _norm(x, params["final_norm"], cfg)
@@ -566,6 +691,24 @@ def _decode_attn(params: Dict, x: jax.Array, lcache: Dict, pos: jax.Array,
     o = decode_attention(q, k_cache, v_cache, lengths)
     o = dense(o.reshape(B, 1, Hq * D), params["wo"])
     return o, {"k": k_cache, "v": v_cache}
+
+
+def _decode_mla(params: Dict, x: jax.Array, lcache: Dict, pos: jax.Array,
+                cfg: ArchConfig):
+    """One MLA decode step: the cache holds the latent and the rotated
+    rope key per position; keys and values are re-expanded from it."""
+    B = x.shape[0]
+    p = jnp.broadcast_to(pos[None, None], (B, 1))
+    q, ckv, k_pe = _mla_qkv(params, x, cfg, p)
+    caches = {}
+    for name, new in (("ckv", ckv), ("k_pe", k_pe)):
+        caches[name] = jax.lax.dynamic_update_slice_in_dim(
+            lcache[name], new.astype(lcache[name].dtype), pos, axis=1)
+    k, v = _mla_kv(params, caches["ckv"], caches["k_pe"], cfg)
+    T = k.shape[1]
+    lengths = jnp.minimum(pos + 1, T) * jnp.ones((B,), jnp.int32)
+    o = decode_attention(q, k, v, lengths, scale=_mla_rope(cfg)[2])
+    return dense(o.reshape(B, 1, -1), params["o_proj"]), caches
 
 
 def _decode_recurrent(params: Dict, x: jax.Array, lcache: Dict,

@@ -9,18 +9,22 @@ Features wired for scale:
   * activation checkpointing (remat) per layer,
   * frozen-parameter masks (updates zeroed; paths exported for Chipmink's
     active-variable filter — provably clean pods),
+  * expert-row masks inside stacked expert leaves (expert-specialized
+    fine-tuning, ESFT: only the listed experts train; the other rows keep
+    their parameters and optimizer state bit-identical),
   * optional int8 error-feedback gradient compression,
-  * MoE touch-report: per-window expert token counts returned in metrics,
-    consumed by the AVF (untouched experts ⇒ clean parameter/optimizer
-    pods).
+  * MoE touch-report: the step's routed-token counts per expert,
+    returned in metrics.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..configs.base import ArchConfig
 from ..models.model import api
@@ -48,9 +52,50 @@ def _zero_frozen(grads: Any, frozen: Sequence[str], prefix=()) -> Any:
     return jnp.zeros_like(grads) if is_frozen(prefix, frozen) else grads
 
 
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def expert_row_masks(cfg: ArchConfig,
+                     expert_mask: Mapping[Any, Sequence[int]]
+                     ) -> Dict[str, np.ndarray]:
+    """{MoE layer index: (held,) bool, True for the held experts that
+    train} from {layer index: held-expert indices}; a MoE layer that the
+    mapping leaves out trains none of its experts."""
+    want = {str(k): tuple(int(e) for e in v) for k, v in expert_mask.items()}
+    held = cfg.moe.held
+    out = {}
+    for i, (_mixer, ffn) in enumerate(cfg.layer_plan()):
+        if ffn == "moe":
+            rows = np.zeros(held, bool)
+            rows[list(want.pop(str(i), ()))] = True
+            out[str(i)] = rows
+    if want:
+        raise ValueError(f"expert mask names layers without experts: "
+                         f"{sorted(want)}")
+    return out
+
+
+def _select_rows(new: Any, old: Any, masks: Dict[str, np.ndarray],
+                 path=()) -> Any:
+    """`new`, except in the expert rows that `masks` does not train, which
+    come from `old`.  Walks a params tree or an optimizer state whose
+    subtrees mirror it (AdamW's `mu`/`nu`, Adafactor's factors)."""
+    if isinstance(new, dict):
+        return {k: _select_rows(new[k], old[k], masks, path + (k,))
+                for k in new}
+    for j in range(len(path) - 3):
+        if (path[j] == "layers" and path[j + 1] in masks
+                and path[j + 2] == "ffn" and path[j + 3] in EXPERT_LEAVES):
+            rows = masks[path[j + 1]]
+            rows = rows.reshape(rows.shape + (1,) * (new.ndim - 1))
+            return jnp.where(rows, new, old)
+    return new
+
+
 def make_train_step(cfg: ArchConfig, opt_cfg: OptConfig, *,
                     microbatches: int = 1,
                     frozen: Sequence[str] = (),
+                    expert_mask: Optional[Mapping[Any, Sequence[int]]] = None,
                     grad_compress: bool = False,
                     q_chunk: Optional[int] = None,
                     remat: Optional[bool] = None,
@@ -67,6 +112,8 @@ def make_train_step(cfg: ArchConfig, opt_cfg: OptConfig, *,
     # masks walk the params tree
     frozen = tuple(f[len("params/"):] if f.startswith("params/") else f
                    for f in frozen)
+    masks = None if expert_mask is None else expert_row_masks(cfg,
+                                                              expert_mask)
 
     def loss_fn(params, mb):
         return m.loss_fn(params, mb, cfg, q_chunk=q_chunk, remat=remat)
@@ -114,6 +161,9 @@ def make_train_step(cfg: ArchConfig, opt_cfg: OptConfig, *,
             metrics["nll"] = loss
 
         grads = _zero_frozen(grads, frozen)
+        if masks is not None:
+            grads = _select_rows(grads, jax.tree.map(jnp.zeros_like, grads),
+                                 masks)
         new_ef = None
         if grad_compress:
             grads, new_ef = tree_quantize_dequantize(grads, state.get("ef"))
@@ -129,6 +179,9 @@ def make_train_step(cfg: ArchConfig, opt_cfg: OptConfig, *,
                             for k in new}
                 return old if is_frozen(prefix, frozen) else new
             new_params = keep_frozen(new_params, params)
+        if masks is not None:
+            new_params = _select_rows(new_params, params, masks)
+            new_opt = _select_rows(new_opt, state["opt"], masks)
 
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
@@ -145,15 +198,17 @@ def touched_prefixes_from_metrics(cfg: ArchConfig, metrics: Dict,
                                   frozen: Sequence[str] = ()) -> Optional[List[str]]:
     """Derive Chipmink `touched_prefixes` from the step's touch report.
 
-    For MoE models, `expert_counts` (n_moe_layers, X) marks experts that
-    received tokens this window; untouched experts' parameter/optimizer
-    pods are provably clean.  Returns None (= everything may be touched)
-    when no report is available.
+    For MoE models the report is `expert_counts` (n_moe_layers, X), the
+    last step's routed tokens per expert.  It does not prove an expert
+    clean: under AdamW an expert that got no token still moves (weight
+    decay; moments that decay from earlier steps), so every expert leaf
+    that is not frozen is touched.  Rows that an ESFT `expert_mask`
+    freezes inside a touched leaf stay bit-identical, and chunk-level
+    change detection finds them clean.  Returns None (= everything may be
+    touched) when no report is available.
     """
     if "expert_counts" not in metrics or cfg.moe is None:
         return None
-    import numpy as np
-    counts = np.asarray(metrics["expert_counts"])  # (n_moe_layers, X)
     plan = cfg.layer_plan()
     moe_layers = [i for i, (_mx, f) in enumerate(plan) if f == "moe"]
     touched: List[str] = []
@@ -164,18 +219,15 @@ def touched_prefixes_from_metrics(cfg: ArchConfig, metrics: Dict,
     touched.append("params/final_norm")
     if cfg.vlm is not None:
         touched.append("params/patch_proj")
-    for li, layer in enumerate(moe_layers):
+    for layer in moe_layers:
         base = f"params/layers/{layer}"
         for name in ("norm1", "norm2"):
             touched.append(f"{base}/{name}")
         touched.append(f"{base}/attn")
-        for shared in ("shared_gate", "shared_up", "shared_down", "router"):
-            touched.append(f"{base}/ffn/{shared}")
-        # expert tensors are row-sliced per expert; the AVF works at leaf
-        # granularity, so any active expert marks the leaf as active —
-        # chunk-level change detection then isolates the dirty expert rows
-        if counts[li].max() > 0:
-            touched.append(f"{base}/ffn")
+        # the AVF works at leaf granularity and the expert leaves stack
+        # every expert's rows; chunk-level change detection isolates the
+        # rows that moved
+        touched.append(f"{base}/ffn")
     for i, (_mx, f) in enumerate(plan):
         if f != "moe":
             touched.append(f"params/layers/{i}")

@@ -117,6 +117,24 @@ def test_change_detector_dirty_tracking():
     assert len(r3.dirty) == 1
 
 
+def test_change_detector_counts_partly_dirty_leaves():
+    """Leaves with some chunks dirty and some clean: their dirty bytes and
+    their whole size; a wholly dirty or clean leaf counts in neither."""
+    rng = np.random.default_rng(3)
+    state = {"part": rng.standard_normal((1024, 8)).astype(np.float32),
+             "whole": rng.standard_normal((64, 4)).astype(np.float32),
+             "clean": rng.standard_normal((512, 8)).astype(np.float32)}
+    cd = ChangeDetector(chunk_bytes=1 << 10)
+    first = cd.detect(build_graph(state, chunk_bytes=1 << 10))
+    assert first.partly_dirty_bytes == first.bytes_in_partly_dirty == 0
+    state["part"][:64] += 1          # 2 of its 32 chunks
+    state["whole"] += 1              # its one chunk
+    r = cd.detect(build_graph(state, chunk_bytes=1 << 10))
+    assert len(r.dirty) == 3
+    assert r.partly_dirty_bytes == 2 * 1024
+    assert r.bytes_in_partly_dirty == state["part"].nbytes
+
+
 def test_change_detector_inactive_inherits():
     rng = np.random.default_rng(2)
     state = {"a": rng.standard_normal((64, 8)).astype(np.float32),

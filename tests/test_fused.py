@@ -171,6 +171,33 @@ def test_half_speculated_leaf_crosses_whole(monkeypatch):
             assert res.payload[node.key] == chunk_slice(arr, node).tobytes()
 
 
+def test_speculation_sets_compile_nothing_new():
+    """Speculated rows are gathered in pieces of one compiled shape a
+    bucket, built when the plan first speculates: a later speculation
+    set of any size compiles no program."""
+    from jax._src import monitoring
+    items = [(f"l{i}", jnp.arange(16 * 256, dtype=jnp.float32) + i)
+             for i in range(4)]
+    keys = digest_leaves(items, chunk_bytes=1 << 10).keys
+    digest_leaves_fused(items, chunk_bytes=1 << 10, spec_keys=set(keys))
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for step in (3, 5, 7, 16):      # sparse sets of 22, 13, 10, 4 rows
+            spec = set(keys[::step])
+            res, _ = digest_leaves_fused(items, chunk_bytes=1 << 10,
+                                         spec_keys=spec)
+            assert set(res.payload) == spec
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+
+
 def test_dense_gather_fetches_whole_leaves(monkeypatch):
     """A leaf with at least half its chunks requested crosses as the
     array itself (no device copy); a sparsely requested leaf is sliced

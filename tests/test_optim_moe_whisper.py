@@ -8,7 +8,8 @@ import pytest
 
 from repro.configs import ARCHS
 from repro.models.model import api, init_model_params
-from repro.models.moe import MoEConfig, _capacity, moe_ffn, router_dispatch
+from repro.models.moe import (MoEConfig, _capacity, moe_ffn, routed_experts,
+                              router_dispatch)
 from repro.train.optimizer import (OptConfig, adafactor_init,
                                    adafactor_update, adamw_init,
                                    adamw_update, clip_by_global_norm,
@@ -147,6 +148,56 @@ def test_moe_expert_counts_feed_avf():
     _y, _aux, counts = moe_ffn(x, params, cfg)
     c = np.asarray(counts)
     assert c[0] > 0 and (c[1:] == 0).all()
+
+
+def test_routed_experts_ignore_rows_past_the_held_groups(monkeypatch):
+    """`ragged_dot` leaves the rows past its last group unspecified (a TPU
+    leaves what the buffer held): with those rows NaN, the held experts'
+    output and every gradient are finite and unchanged."""
+    spec = dataclasses.replace(
+        ARCHS["deepseek-v2-lite"].moe, n_experts=16, top_k=4, expert_ff=8,
+        n_held=4)
+    rng = np.random.default_rng(0)
+    E, H = 8, spec.held
+
+    def arr(*shape):
+        return jnp.asarray(rng.standard_normal(shape) * 0.3, jnp.float32)
+
+    params = {"router": arr(E, 16), "w_gate": arr(H, E, 8),
+              "w_up": arr(H, E, 8), "w_down": arr(H, 8, E)}
+    x = arr(2, 6, E)
+
+    def loss(p, x):
+        y, _counts, _choice = routed_experts(x, p, spec)
+        return jnp.sum(y * y), y
+
+    want = jax.grad(loss, argnums=(0, 1), has_aux=True)(params, x)
+    real = jax.lax.ragged_dot
+
+    def nan_past(a, sizes):
+        past = jnp.arange(a.shape[0]) >= jnp.sum(sizes)
+        return jnp.where(past[:, None], jnp.nan, a)
+
+    # NaN past the groups in the product and in the rows' cotangent
+    @jax.custom_vjp
+    def poisoned(lhs, rhs, group_sizes):
+        return nan_past(real(lhs, rhs, group_sizes), group_sizes)
+
+    def fwd(lhs, rhs, group_sizes):
+        return poisoned(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def bwd(res, g):
+        lhs, rhs, sizes = res
+        d_lhs, d_rhs = jax.vjp(lambda a, b: real(a, b, sizes), lhs, rhs)[1](g)
+        return nan_past(d_lhs, sizes), d_rhs, None
+
+    poisoned.defvjp(fwd, bwd)
+    monkeypatch.setattr(jax.lax, "ragged_dot", poisoned)
+    got = jax.grad(loss, argnums=(0, 1), has_aux=True)(params, x)
+    assert int(np.asarray(routed_experts(x, params, spec)[1]).sum()) < 48
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 # ---------------------------------------------------------------------------
