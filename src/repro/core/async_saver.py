@@ -1,8 +1,9 @@
 """Asynchronous saving (paper §6.1–§6.2), double-buffered.
 
 A single background *podding thread* runs the heavy half of a save
-(digesting, podding, serialization, storage writes) while the training/
-serving loop continues.  Two non-reentrant locks suffice (§6.2):
+(digesting, podding, serialization, storage writes; the last two on a
+few put threads where the store lets its puts overlap) while the
+training/serving loop continues.  Two non-reentrant locks suffice (§6.2):
 
   * ``l_ns``     — namespace lock: makes shared host-side structures
                    (thesaurus, store indices) thread-safe;

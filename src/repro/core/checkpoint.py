@@ -35,8 +35,9 @@ with the *delta*, mirroring the device half's batched digest engine:
     the async saver (depth 2) no longer joins the previous save: save
     N's decide/gather/write overlaps step N+1's compute, and save bodies
     retire strictly FIFO so cross-save state (digest table, previous
-    assignment, thesaurus) is race-free.  Thesaurus/store mutation is
-    additionally serialized under the namespace lock ``l_ns``.
+    assignment, thesaurus) is race-free.  Thesaurus mutation and the
+    commit are additionally serialized under the namespace lock
+    ``l_ns``; pod puts need no lock (write phase, below).
 
 Single-sync save (``fused=True``, default)
 ------------------------------------------
@@ -121,7 +122,7 @@ Every save is a transaction with a strictly ordered commit protocol:
 
   1. **pods** — content-addressed payload blobs.  Each is written
      tmp-file + atomic rename on the file backend; a crash mid-pod
-     leaves only a ``.tmp`` orphan, never a half blob at a live address.
+     leaves only ``.tmp`` orphans, never a half blob at a live address.
   2. **manifest** — one atomic write naming every pod digest.  This is
      the commit point for the *data*: once the manifest exists and all
      its pods exist, the commit is complete and loadable.
@@ -228,11 +229,14 @@ copy-off opt-out) disables the guard with the copies.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import time as _time
 import warnings
-from typing import Any, Callable, Dict, Iterable, List, Optional, Set
+from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
+from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
+                    Set)
 
 import msgpack
 import numpy as np
@@ -255,6 +259,18 @@ from .thesaurus import PodThesaurus
 from .volatility import FlipTracker
 
 TimeID = int
+
+#: threads that put one save's pods at once, where the store lets its
+#: puts overlap (`BaseStore.concurrent_puts`) and a trial found that
+#: they pay (`Chipmink._pod_writes`)
+PUT_THREADS = 4
+#: pods dispatched and not yet collected, per put thread
+PUTS_IN_FLIGHT_PER_THREAD = 2
+#: pods the trial puts in order before it puts the rest on the threads
+PUT_TRIAL_PODS = 64
+#: the threads are kept if a pod on them took at most this share of the
+#: time a pod in order took
+PUT_TRIAL_GAIN = 0.8
 
 #: meta blob holding the next unissued TimeID (multi-writer mode only):
 #: a CAS counter, so concurrent writers never mint the same commit id.
@@ -374,6 +390,10 @@ class Chipmink:
             self.refcounts.ensure()
         #: tips orphaned by delete_branch, awaiting an incremental gc()
         self._gc_backlog: List[TimeID] = []
+        #: threads of the write phase, made by the first save that uses them
+        self._put_pool: Optional[ThreadPoolExecutor] = None
+        #: whether the put threads paid in the trial; None before it
+        self._puts_overlap: Optional[bool] = None
 
     # ------------------------------------------------------------------
     # multi-writer plumbing (leases, fenced TimeIDs)
@@ -431,6 +451,9 @@ class Chipmink:
         any pending save errors), stop the heartbeat, and release the
         writer lease so peers need not wait out its TTL.  Idempotent."""
         errors = self.saver.drain()
+        if self._put_pool is not None:
+            self._put_pool.shutdown()
+            self._put_pool = None
         if self._heartbeat is not None:
             self._heartbeat.stop()
             self._heartbeat = None
@@ -831,14 +854,17 @@ class Chipmink:
                     graph, gather_nodes, phases=ph)
             stats["n_gather_syncs"] = gather_syncs
 
-        # write phase: serialize + store from the prefetched host bytes.
-        # Thesaurus/store mutation is serialized under the namespace lock,
-        # taken per pod so serialization itself never blocks concurrent
-        # readers (save bodies are FIFO already; l_ns shields readers).
-        # Each store write retries transient I/O errors with backoff
-        # (retry_policy); puts are idempotent — a pod is content-addressed
-        # and the rename is atomic — so a retried step never double-
-        # applies.  InjectedCrash (BaseException) punches through.
+        # write phase: serialize + store from the prefetched host bytes,
+        # one task a pod (`_pod_writes`): on a few threads where the store
+        # lets puts overlap and a trial found that they pay, else in order
+        # on this thread.  The put needs no namespace lock: a pod is
+        # content-addressed and lands by atomic rename, no reader looks
+        # for it before a manifest names it, in-process GC drains saves
+        # first, and a multi-writer intent pinned every digest above.
+        # l_ns shields the thesaurus, taken here per pod, and the commit.  Each put retries transient I/O
+        # errors with backoff (retry_policy); puts are idempotent, so a
+        # retried step never double-applies.  InjectedCrash
+        # (BaseException) punches through.
         with ph("write"):
             n_retries = 0
             # delta-chain eligibility for this save: only the reuse path
@@ -852,54 +878,67 @@ class Chipmink:
             n_delta_pods = 0
             chain_depth_max = 0
             stats.update(t_serialize=0.0, t_delta_encode=0.0,
-                         t_store_put=0.0, t_ns_wait=0.0,
+                         t_store_put=0.0, t_ns_wait=0.0, t_put_wall=0.0,
                          bytes_serialized=0)
             fs_calls_before = self.store.stats.pod_fs_calls
-            for pid, pod, dig_hex, digest in to_write:
-                with ph("write.serialize", "t_serialize"):
+            def write_pod(entry) -> tuple:
+                """Serialize, delta-encode and put one pod.  Returns the
+                entry, whether the put wrote, the delta base it was
+                stored against, its chain depth, its retries and its own
+                timers and counters."""
+                pid, pod, dig_hex, digest = entry
+                own = Phases({}, time_id)
+                with own("write.serialize", "t_serialize"):
                     data = serialize_pod(pod, graph, asg, chunk_bytes_of)
-                ph.add("bytes_serialized", len(data))
+                own.add("bytes_serialized", len(data))
 
                 delta_blob = base_hex = None
                 delta_depth = 0
                 base = prev_pod_digests.get(pid) if delta_eligible else None
                 if base is not None and base != digest:
-                    with ph("write.delta_encode", "t_delta_encode"):
+                    with own("write.delta_encode", "t_delta_encode"):
                         delta_blob, base_hex, delta_depth = \
                             self._delta_candidate(pod, graph, report, data,
                                                   base, scalar_changed)
 
-                def put_one(dig_hex=dig_hex, data=data, digest=digest,
-                            delta_blob=delta_blob) -> bool:
-                    t0 = _time.perf_counter()
-                    with self.saver.l_ns:
-                        ph.add("t_ns_wait", _time.perf_counter() - t0)
-                        with ph("write.store_put", "t_store_put"):
-                            if not self.enable_cd:
-                                self.store.put_pod(dig_hex, data)
-                                return True
-                            if delta_blob is not None:
-                                fresh = self.store.put_pod_delta(dig_hex,
-                                                                 delta_blob)
-                            else:
-                                fresh = self.store.put_pod(dig_hex, data)
-                            self.thesaurus.insert(digest, dig_hex)
-                            return fresh
+                def put() -> bool:
+                    with own("write.store_put", "t_store_put"):
+                        if not self.enable_cd:
+                            self.store.put_pod(dig_hex, data)
+                            return True
+                        if delta_blob is not None:
+                            return self.store.put_pod_delta(dig_hex,
+                                                            delta_blob)
+                        return self.store.put_pod(dig_hex, data)
 
-                fresh, nr = call_with_retries(put_one, self.retry_policy)
-                n_retries += nr
-                if fresh:
+                fresh, nr = call_with_retries(put, self.retry_policy)
+                return entry, fresh, base_hex, delta_depth, nr, own.stats
+
+            with ph("write.puts", "t_put_wall"), \
+                    contextlib.closing(self._pod_writes(
+                        to_write, write_pod)) as results:
+                for entry, fresh, base_hex, depth, nr, timers in results:
+                    pid, _, dig_hex, digest = entry
+                    for key, value in timers.items():
+                        ph.add(key, value)
+                    n_retries += nr
+                    if self.enable_cd:
+                        t0 = _time.perf_counter()
+                        with self.saver.l_ns:
+                            ph.add("t_ns_wait", _time.perf_counter() - t0)
+                            self.thesaurus.insert(digest, dig_hex)
+                    if not fresh:
+                        aliased += 1          # disk-level synonym
+                        continue
                     written += 1
-                    if delta_blob is not None:
+                    if base_hex is not None:
                         # the manifest records chain structure only for
                         # pods this commit actually stored in delta form
                         # (a dedup hit keeps whatever form the digest
                         # already has).
                         pods_meta[pid]["delta_of"] = base_hex
                         n_delta_pods += 1
-                        chain_depth_max = max(chain_depth_max, delta_depth)
-                else:
-                    aliased += 1              # disk-level synonym
+                        chain_depth_max = max(chain_depth_max, depth)
             stats["n_retries"] = n_retries
             stats["pods_written"] = written
             stats["pods_aliased"] = aliased
@@ -962,6 +1001,66 @@ class Chipmink:
                     pass
             self._prev_pods = asg
             self._prev_graph = graph
+
+    def _pod_writes(self, jobs: List[tuple],
+                    write_pod: Callable[[tuple], tuple]) -> Iterator[tuple]:
+        """`write_pod` of each job, in order on this thread, or on
+        `PUT_THREADS` threads where the store lets its puts overlap and a
+        trial found that they pay.  The trial is the first save with
+        2 × `PUT_TRIAL_PODS` jobs or more: it runs `PUT_TRIAL_PODS` jobs
+        in order and the rest on the threads, and the instance keeps the
+        threads for its later saves of two jobs or more if a job on them
+        took at most `PUT_TRIAL_GAIN` of the wall time a job in order
+        took.  A filesystem that makes each call wait overlaps the waits
+        across threads; one bound by copying memory under the interpreter
+        lock does not, and is left in order."""
+        trial = (self.store.concurrent_puts and self._puts_overlap is None
+                 and len(jobs) >= 2 * PUT_TRIAL_PODS)
+        if not (trial or (self._puts_overlap and len(jobs) >= 2)):
+            for job in jobs:
+                yield write_pod(job)
+            return
+        if trial:
+            t0 = _time.perf_counter()
+            for job in jobs[:PUT_TRIAL_PODS]:
+                yield write_pod(job)
+            jobs = jobs[PUT_TRIAL_PODS:]
+            t1 = _time.perf_counter()
+        yield from self._pooled_writes(jobs, write_pod)
+        if trial:
+            self._puts_overlap = ((_time.perf_counter() - t1) / len(jobs)
+                                  <= PUT_TRIAL_GAIN * (t1 - t0)
+                                  / PUT_TRIAL_PODS)
+
+    def _pooled_writes(self, jobs: List[tuple],
+                       write_pod: Callable[[tuple], tuple]
+                       ) -> Iterator[tuple]:
+        """`write_pod` of each job on the put threads, the results in the
+        order they complete, with at most `PUTS_IN_FLIGHT_PER_THREAD`
+        jobs a thread dispatched and not yet collected.  The first error
+        stops the dispatch: jobs not started are cancelled, those running
+        are waited for, and the error is raised here."""
+        if self._put_pool is None:
+            self._put_pool = ThreadPoolExecutor(
+                PUT_THREADS, thread_name_prefix="chipmink-put")
+        limit = PUT_THREADS * PUTS_IN_FLIGHT_PER_THREAD
+        pending: Set[Any] = set()
+        try:
+            for job in jobs:
+                if len(pending) >= limit:
+                    done, pending = wait(pending,
+                                         return_when=FIRST_COMPLETED)
+                    for future in done:
+                        yield future.result()
+                pending.add(self._put_pool.submit(write_pod, job))
+            while pending:
+                done, pending = wait(pending, return_when=FIRST_COMPLETED)
+                for future in done:
+                    yield future.result()
+        finally:
+            for future in pending:
+                future.cancel()
+            wait(pending)
 
     def _delta_candidate(self, pod, graph, report, data, base,
                          scalar_changed) -> tuple:

@@ -19,11 +19,16 @@ Phases of one save (``save_stats`` key in brackets):
                   ``gather``, ``write`` and ``commit``; ``digest``
                   holds ``digest.dispatch``, ``digest.fetch``,
                   ``digest.unpack`` and ``digest.diff``; ``write`` holds
-                  per-pod ``write.serialize`` (``t_serialize``),
-                  ``write.delta_encode`` (``t_delta_encode``) and
-                  ``write.store_put`` (``t_store_put``; the wait for the
-                  namespace lock before it is ``t_ns_wait``); ``commit``
-                  (``t_commit``)
+                  ``write.puts`` (``t_put_wall``: from the first pod's
+                  dispatch to the last pod's result; the wait for the
+                  namespace lock before each pod's thesaurus entry is
+                  ``t_ns_wait``); ``commit`` (``t_commit``)
+  put threads     per pod ``write.serialize`` (``t_serialize``),
+  (or podding)    ``write.delta_encode`` (``t_delta_encode``) and
+                  ``write.store_put`` (``t_store_put``), each summed
+                  over the save's pods, so overlapping pods add up past
+                  ``t_put_wall``; on the podding thread where the store
+                  keeps its puts in order (`BaseStore.concurrent_puts`)
 
 Counters: ``bytes_fetched`` (every byte brought from device to host),
 ``bytes_dirty`` (true lengths of the dirty chunks),

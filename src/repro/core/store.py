@@ -17,7 +17,8 @@ Crash consistency
 -----------------
 Every write on the file backend is tmp + `os.replace`, so a crash leaves
 an object either fully present or fully absent — never truncated — plus
-at most one orphan ``.tmp`` file (debris that `sweep_tmp` / fsck removes).
+an orphan ``.tmp`` file for each write it cut short (debris that
+`sweep_tmp` / fsck removes).
 The metadata blobs additionally support `compare_and_put_meta`, an atomic
 compare-and-swap keyed on the blob's previous bytes: the primitive the
 commit DAG uses to advance refs so a concurrent writer or a GC sweeper
@@ -73,9 +74,12 @@ checkout already rely on).  The contract:
 """
 from __future__ import annotations
 
+import contextlib
+import itertools
 import os
 import threading
 import time
+import uuid
 import weakref
 import zlib
 from typing import Any, Dict, Iterable, List, Optional, Set, Tuple
@@ -143,10 +147,17 @@ class StoreStats:
 
 class BaseStore:
     compress: bool = False
+    #: `put_pod` and `put_pod_delta` may run on several threads at once,
+    #: their I/O overlapping; a save then puts its pods from a few
+    #: threads (`core/checkpoint.py`, write phase).  Off, a put holds the
+    #: store's lock and saves put their pods in order.
+    concurrent_puts: bool = False
 
     def __init__(self) -> None:
         self.stats = StoreStats()
         self._lock = threading.Lock()
+        #: held for each update of the counters that concurrent puts share
+        self._stats_lock = threading.Lock()
         #: delta digest -> base digest, lazily filled on chain walks and
         #: invalidated when the delta form is deleted/re-materialized.
         self._chain_cache: Dict[str, str] = {}
@@ -264,15 +275,7 @@ class BaseStore:
     def put_pod(self, digest_hex: str, data: bytes) -> bool:
         """Write pod bytes (whole form) unless the digest is already
         present in either form.  Returns True if written."""
-        with self._lock:
-            if self.has_pod(digest_hex):
-                self.stats.pods_deduped += 1
-                return False
-            blob = self._encode_blob(data)
-            self._put_raw(digest_hex, blob)
-            self.stats.pods_written += 1
-            self.stats.pod_bytes_written += len(blob)
-            return True
+        return self._put_form(digest_hex, data, delta=False)
 
     def put_pod_delta(self, digest_hex: str, delta_blob: bytes) -> bool:
         """Store `digest_hex` in delta form (a `core/delta.py` blob whose
@@ -283,17 +286,29 @@ class BaseStore:
         chain must reproduce exactly the bytes `digest_hex` names, and
         chain depth must respect its `DeltaPolicy` (the store enforces
         only the hard `MAX_WALK` cycle guard on reads)."""
-        with self._lock:
-            if self.has_pod(digest_hex):
+        return self._put_form(digest_hex, delta_blob, delta=True)
+
+    def _put_form(self, digest_hex: str, data: bytes, delta: bool) -> bool:
+        """Probe, then write one physical form.  The store's lock is held
+        across both unless `concurrent_puts`; the counters are updated
+        under their own lock either way."""
+        with (contextlib.nullcontext() if self.concurrent_puts
+              else self._lock):
+            fresh = not self.has_pod(digest_hex)
+            if fresh:
+                blob = self._encode_blob(data)
+                (self._put_delta_raw if delta else self._put_raw)(
+                    digest_hex, blob)
+        with self._stats_lock:
+            if not fresh:
                 self.stats.pods_deduped += 1
                 return False
-            blob = self._encode_blob(delta_blob)
-            self._put_delta_raw(digest_hex, blob)
             self.stats.pods_written += 1
             self.stats.pod_bytes_written += len(blob)
-            self.stats.delta_pods_written += 1
-            self.stats.delta_bytes_written += len(blob)
-            return True
+            if delta:
+                self.stats.delta_pods_written += 1
+                self.stats.delta_bytes_written += len(blob)
+        return True
 
     def _resolve_full_locked(self, digest_hex: str):
         """Resolve a digest to its full pod bytes, walking the delta
@@ -631,6 +646,15 @@ class FileStore(BaseStore):
     directory would.  Dedup still reads the disk on every put.  Where
     the platform names nothing relative to a descriptor, every call
     takes the full path.  Manifests and meta use full paths.
+
+    Puts may run on several threads at once (``concurrent_puts``): the
+    dedup probe and the write hold no lock, the counters are updated
+    under one, and a shard new to the instance is made once.  Every
+    write goes through a temporary of its own,
+    ``<digest>.pod.<unique>.tmp``, so two writers of one digest, threads
+    or processes, never truncate or rename each other's; the name still
+    ends in ``.tmp`` for `sweep_tmp`.  Both land the same bytes by
+    atomic rename, so either may win.
     """
 
     #: how long compare_and_put_meta spins on another LIVE process's
@@ -640,6 +664,8 @@ class FileStore(BaseStore):
     #: a lock older than this is stale even if its owner pid is alive
     #: (the pid may have been recycled, or the owner hung mid-CAS).
     STALE_LOCK_AGE_S = 5.0
+
+    concurrent_puts = True
 
     def __init__(self, root: str, compress: bool = False,
                  fsync: bool = False) -> None:
@@ -661,14 +687,20 @@ class FileStore(BaseStore):
             weakref.finalize(self, _close_all, self._pods_fds)
         #: shard directories this instance has made or found
         self._shards: Set[str] = set()
+        self._shard_lock = threading.Lock()
         self._repin_lock = threading.Lock()
+        #: temporaries are ``<name>.<tag><n>.tmp``: the tag tells this
+        #: instance from every other writer, the count its own writes
+        self._tmp_tag = uuid.uuid4().hex[:12]
+        self._tmp_seq = itertools.count()
 
     # -- atomic write primitive -------------------------------------------
     def _write_atomic(self, path: str, data: bytes,
                       dir_fd: Optional[int] = None) -> None:
         """Write `data` to `path` (relative to `dir_fd` if given) through
-        ``<path>.tmp`` and an atomic rename."""
-        tmp = path + ".tmp"
+        a temporary of its own, ``<path>.<unique>.tmp``, and an atomic
+        rename."""
+        tmp = f"{path}.{self._tmp_tag}{next(self._tmp_seq)}.tmp"
         fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o666,
                      dir_fd=dir_fd)
         try:
@@ -727,8 +759,12 @@ class FileStore(BaseStore):
         name, dir_fd = self._at(digest_hex, suffix)
         return fn(name, *args, dir_fd=dir_fd)
 
+    def _count_fs_calls(self, n: int) -> None:
+        with self._stats_lock:
+            self.stats.pod_fs_calls += n
+
     def _exists(self, digest_hex: str, suffix: str) -> bool:
-        self.stats.pod_fs_calls += 1
+        self._count_fs_calls(1)
         try:
             self._pod_call(os.stat, digest_hex, suffix)
         except (OSError, ValueError):
@@ -760,15 +796,8 @@ class FileStore(BaseStore):
         for retry in (False, True):
             name, dir_fd = self._at(digest_hex, suffix)
             try:
-                shard = digest_hex[:2]
-                if shard not in self._shards:
-                    self.stats.pod_fs_calls += 1
-                    try:
-                        os.mkdir(os.path.dirname(name), dir_fd=dir_fd)
-                    except FileExistsError:
-                        pass
-                    self._shards.add(shard)
-                self.stats.pod_fs_calls += 3 if self.fsync else 2
+                self._make_shard(os.path.dirname(name), dir_fd)
+                self._count_fs_calls(3 if self.fsync else 2)
                 self._write_atomic(name, data, dir_fd)
                 return
             except FileNotFoundError:
@@ -777,6 +806,21 @@ class FileStore(BaseStore):
                 self._shards.clear()
                 os.makedirs(self._pods_dir, exist_ok=True)
                 self._repin(dir_fd)
+
+    def _make_shard(self, shard: str, dir_fd: Optional[int]) -> None:
+        """Make a shard directory the first time this instance writes to
+        it; concurrent puts to one new shard make it once."""
+        if shard in self._shards:
+            return
+        with self._shard_lock:
+            if shard in self._shards:
+                return
+            self._count_fs_calls(1)
+            try:
+                os.mkdir(shard, dir_fd=dir_fd)
+            except FileExistsError:
+                pass
+            self._shards.add(shard)
 
     def _has_whole(self, digest_hex: str) -> bool:
         return self._exists(digest_hex, ".pod")

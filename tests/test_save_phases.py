@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import Chipmink, MemoryStore
+from repro.core import Chipmink, FileStore, MemoryStore
 from repro.core.async_saver import AsyncSaver, QueueTimes
 
 #: the top-level phases of a save body, in order
@@ -20,7 +20,8 @@ BODY_PHASES = ("avf", "digest", "observe", "podding", "decide", "intent",
                "gather", "write", "commit")
 NEW_KEYS = ({"t_submit_wait", "t_queue", "t_body", "t_digest_dispatch",
              "t_digest_fetch", "t_digest_unpack", "t_digest_diff",
-             "t_serialize", "t_store_put", "t_ns_wait", "bytes_fetched",
+             "t_serialize", "t_store_put", "t_ns_wait", "t_put_wall",
+             "bytes_fetched",
              "bytes_dirty", "bytes_serialized", "n_store_fs_calls",
              "bytes_partly_dirty", "bytes_in_partly_dirty"}
             | {f"t_{p}" for p in BODY_PHASES})
@@ -124,6 +125,24 @@ def test_top_level_phases_tile_the_body():
         parts = (st["t_serialize"] + st["t_delta_encode"]
                  + st["t_store_put"] + st["t_ns_wait"])
         assert parts <= st["t_write"]
+
+
+def test_puts_on_threads_lie_inside_the_write_phase(tmp_path):
+    # a store whose puts overlap: the pods are put from a few threads,
+    # and the phases of the podding thread still tile the body
+    ck = Chipmink(FileStore(str(tmp_path), compress=True),
+                  chunk_bytes=1 << 15, async_mode=True)
+    ck._puts_overlap = True
+    for step in range(4):
+        ck.save(_state(step, n=1 << 17))
+    ck.wait()
+    assert ck._put_pool is not None
+    for st in ck.save_stats:
+        total = sum(st[f"t_{p}"] for p in BODY_PHASES)
+        assert abs(total - st["t_body"]) <= 0.01 * st["t_body"], st
+        assert 0 < st["t_put_wall"] <= st["t_write"]
+        assert st["t_ns_wait"] <= st["t_put_wall"]
+    ck.close()
 
 
 def test_byte_counters():
